@@ -124,18 +124,29 @@ def decode_attention_partial(q, ck, cv, cpos, pos, *, window: int = 0,
 
 
 # --------------------------------------------------------------------------
-# fused variant: cache partials + self-attention fold + normalize, one call
+# fused variants: cache blocks + self-attention fold + normalize, one call
 # --------------------------------------------------------------------------
+#
+# TPU blocks must have their last two dims divisible by (8, 128) or equal to
+# the whole array dim. The caches are token-major [B|P, S, Hkv, Dh]; the
+# wrappers view them as [B|P, S, Hkv*Dh] (a free reshape), and each grid step
+# DMAs one kv head's Dh lanes of a block of tokens, so the K/V blocks are
+# (block, Dh): legal for Dh a multiple of 128, or a single kv head. Stored
+# positions ride as [B|P, 1, S] rows; ``pos`` and the paged block table are
+# scalar-prefetched into SMEM.
 
-def _decode_attn_fused_kernel(pos_ref, q_ref, k_ref, v_ref, cpos_ref,
-                              k1_ref, v1_ref, o_ref,
-                              m_ref, l_ref, acc_ref,
-                              *, window: int, softcap: float, block_k: int,
-                              nk: int):
-    """Same online-softmax block loop as ``_decode_attn_kernel``, but the
-    running (m, l, acc) live in VMEM scratch — persistent across the
-    sequential kv-block axis — and the LAST block folds the current
-    token's (k1, v1) contribution and writes the normalized output."""
+def _decode_attn_fused_kernel(*refs, n_prefetch: int, window: int,
+                              softcap: float, nk: int):
+    """Online-softmax block loop with the running (m, l, acc) in VMEM
+    scratch — persistent across the sequential kv-block grid axis. The
+    LAST block folds the current token's (k1, v1) contribution and writes
+    the normalized output. Shared by the contiguous (``n_prefetch`` = 1:
+    pos) and paged (``n_prefetch`` = 2: block table, pos) variants, whose
+    index maps alone differ — so at block_k == page_tokens the two are
+    bit-identical on identical logical content."""
+    pos_ref = refs[n_prefetch - 1]
+    (q_ref, k_ref, v_ref, cpos_ref, k1_ref, v1_ref, o_ref,
+     m_ref, l_ref, acc_ref) = refs[n_prefetch:]
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -145,10 +156,10 @@ def _decode_attn_fused_kernel(pos_ref, q_ref, k_ref, v_ref, cpos_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)          # [G, Dh] (pre-scaled)
-    k = k_ref[0, :, 0].astype(jnp.float32)       # [bk, Dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)       # [bk, Dh]
-    cpos = cpos_ref[0]                           # [bk] int32
-    pos = pos_ref[0]                             # scalar int32
+    k = k_ref[0].astype(jnp.float32)             # [bk, Dh]
+    v = v_ref[0].astype(jnp.float32)             # [bk, Dh]
+    cpos = cpos_ref[0]                           # [1, bk] int32
+    pos = pos_ref[pl.program_id(0)]              # scalar int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [G, bk]
@@ -157,100 +168,45 @@ def _decode_attn_fused_kernel(pos_ref, q_ref, k_ref, v_ref, cpos_ref,
     mask = (cpos >= 0) & (cpos <= pos)
     if window:
         mask &= cpos > (pos - window)
-    s = jnp.where(mask[None, :], s, NEG_INF)
+    s = jnp.where(mask, s, NEG_INF)
 
     m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1)
-    acc_new = acc_prev * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc_new
+    l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_prev * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        k1 = k1_ref[0, 0].astype(jnp.float32)    # [Dh]
-        v1 = v1_ref[0, 0].astype(jnp.float32)    # [Dh]
-        s_self = jax.lax.dot_general(
-            q, k1, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [G]
+        k1 = k1_ref[0].astype(jnp.float32)       # [1, Dh]
+        v1 = v1_ref[0].astype(jnp.float32)       # [1, Dh]
+        s_self = jnp.sum(q * k1, axis=-1, keepdims=True)   # [G, 1]
         if softcap:
             s_self = jnp.tanh(s_self / softcap) * softcap
         m_f = jnp.maximum(m_ref[...], s_self)
         corr_f = jnp.exp(m_ref[...] - m_f)
         p_self = jnp.exp(s_self - m_f)
         l_f = l_ref[...] * corr_f + p_self
-        acc_f = acc_ref[...] * corr_f[:, None] + p_self[:, None] * v1[None]
-        o_ref[0, 0] = acc_f / jnp.maximum(l_f[:, None], 1e-30)
+        acc_f = acc_ref[...] * corr_f + p_self * v1
+        o_ref[0, 0] = acc_f / jnp.maximum(l_f, 1e-30)
 
 
-# --------------------------------------------------------------------------
-# paged variant: kv blocks gathered through a block table, one call
-# --------------------------------------------------------------------------
+def _fused_operands(q, k1, v1, hkv):
+    """Pre-scaled grouped queries [B,Hkv,G,Dh] and the current token's
+    K/V as [B,1,Hkv*Dh] rows (one kv head = one Dh-lane block)."""
+    b, h, dh = q.shape
+    scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
+    qs = (q.astype(jnp.float32) * scale).reshape(b, hkv, h // hkv, dh)
+    return (qs, k1.reshape(b, 1, hkv * dh), v1.reshape(b, 1, hkv * dh))
 
-def _decode_attn_paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref,
-                              cpos_ref, k1_ref, v1_ref, o_ref,
-                              m_ref, l_ref, acc_ref,
-                              *, softcap: float, nk: int):
-    """Fused decode-attention block loop over a PAGED cache: the kv-block
-    grid axis walks the slot's block table (scalar-prefetched ``bt_ref``),
-    and each block's index map resolves the physical page, so the pages
-    stream HBM->VMEM in logical order without materializing a gathered
-    copy. Unmapped blocks resolve to the null page whose positions are all
-    -1 — they mask to an exact no-op, identical to an empty contiguous
-    region. Math and accumulation order match ``_decode_attn_fused_kernel``
-    with block_k == page_tokens, so the paged and contiguous kernels are
-    bit-identical on identical logical content."""
-    ki = pl.program_id(2)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)          # [G, Dh] (pre-scaled)
-    k = k_ref[0, :, 0].astype(jnp.float32)       # [pt, Dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)       # [pt, Dh]
-    cpos = cpos_ref[0]                           # [pt] int32
-    pos = pos_ref[0]                             # scalar int32
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [G, pt]
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    mask = (cpos >= 0) & (cpos <= pos)
-    s = jnp.where(mask[None, :], s, NEG_INF)
-
-    m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1)
-    acc_new = acc_prev * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc_new
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        k1 = k1_ref[0, 0].astype(jnp.float32)    # [Dh]
-        v1 = v1_ref[0, 0].astype(jnp.float32)    # [Dh]
-        s_self = jax.lax.dot_general(
-            q, k1, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [G]
-        if softcap:
-            s_self = jnp.tanh(s_self / softcap) * softcap
-        m_f = jnp.maximum(m_ref[...], s_self)
-        corr_f = jnp.exp(m_ref[...] - m_f)
-        p_self = jnp.exp(s_self - m_f)
-        l_f = l_ref[...] * corr_f + p_self
-        acc_f = acc_ref[...] * corr_f[:, None] + p_self[:, None] * v1[None]
-        o_ref[0, 0] = acc_f / jnp.maximum(l_f[:, None], 1e-30)
+def _fused_scratch(g, dh):
+    return [pltpu.VMEM((g, 1), jnp.float32),     # running max m
+            pltpu.VMEM((g, 1), jnp.float32),     # running denom l
+            pltpu.VMEM((g, dh), jnp.float32)]    # running numerator acc
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -263,52 +219,54 @@ def decode_attention_paged(q, pk, pv, ppos, bt, k1, v1, pos, *,
     table (0 = the reserved null page); k1/v1: [B,Hkv,Dh]; pos: [B].
     Full attention only (paged mode has no sliding-window layers).
     Returns [B,H,Dh] in q's dtype.
+
+    The kv-block grid axis walks the slot's block table and each block's
+    index map resolves the physical page, so the pages stream HBM->VMEM
+    in logical order without materializing a gathered copy. Unmapped
+    blocks resolve to the null page whose positions are all -1 — they
+    mask to an exact no-op, identical to an empty contiguous region.
     """
     b, h, dh = q.shape
-    pt, hkv = pk.shape[1], pk.shape[2]
+    npages, pt, hkv = pk.shape[0], pk.shape[1], pk.shape[2]
     nk = bt.shape[1]
     g = h // hkv
+    qs, k1, v1 = _fused_operands(q, k1, v1, hkv)
 
-    scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    qs = (q.astype(jnp.float32) * scale).reshape(b, hkv, g, dh)
-
-    kernel = functools.partial(_decode_attn_paged_kernel, softcap=softcap,
-                               nk=nk)
+    kernel = functools.partial(_decode_attn_fused_kernel, n_prefetch=2,
+                               window=0, softcap=softcap, nk=nk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, hkv, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi, ki, bt_ref: (bi,),
-                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, g, dh),
-                         lambda bi, hi, ki, bt_ref: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, pt, 1, dh),
-                         lambda bi, hi, ki, bt_ref:
-                         (bt_ref[bi, ki], 0, hi, 0)),
-            pl.BlockSpec((1, pt, 1, dh),
-                         lambda bi, hi, ki, bt_ref:
-                         (bt_ref[bi, ki], 0, hi, 0)),
-            pl.BlockSpec((1, pt),
-                         lambda bi, hi, ki, bt_ref: (bt_ref[bi, ki], 0)),
+                         lambda bi, hi, ki, bt_ref, pos_ref: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, pt, dh),
+                         lambda bi, hi, ki, bt_ref, pos_ref:
+                         (bt_ref[bi, ki], 0, hi)),
+            pl.BlockSpec((1, pt, dh),
+                         lambda bi, hi, ki, bt_ref, pos_ref:
+                         (bt_ref[bi, ki], 0, hi)),
+            pl.BlockSpec((1, 1, pt),
+                         lambda bi, hi, ki, bt_ref, pos_ref:
+                         (bt_ref[bi, ki], 0, 0)),
             pl.BlockSpec((1, 1, dh),
-                         lambda bi, hi, ki, bt_ref: (bi, hi, 0)),
+                         lambda bi, hi, ki, bt_ref, pos_ref: (bi, 0, hi)),
             pl.BlockSpec((1, 1, dh),
-                         lambda bi, hi, ki, bt_ref: (bi, hi, 0)),
+                         lambda bi, hi, ki, bt_ref, pos_ref: (bi, 0, hi)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, hi, ki, bt_ref: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),        # running max m
-            pltpu.VMEM((g,), jnp.float32),        # running denom l
-            pltpu.VMEM((g, dh), jnp.float32),     # running numerator acc
-        ],
+                               lambda bi, hi, ki, bt_ref, pos_ref:
+                               (bi, hi, 0, 0)),
+        scratch_shapes=_fused_scratch(g, dh),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), jnp.float32),
         interpret=interpret,
-    )(bt.astype(jnp.int32), pos.astype(jnp.int32), qs, pk, pv, ppos, k1, v1)
+    )(bt.astype(jnp.int32), pos.astype(jnp.int32), qs,
+      pk.reshape(npages, pt, hkv * dh), pv.reshape(npages, pt, hkv * dh),
+      ppos.reshape(npages, 1, pt), k1, v1)
     return out.reshape(b, h, dh).astype(q.dtype)
 
 
@@ -331,33 +289,31 @@ def decode_attention_fused(q, ck, cv, cpos, k1, v1, pos, *, window: int = 0,
         bk //= 2
     bk = max(bk, 1)
     nk = sc // bk
+    qs, k1, v1 = _fused_operands(q, k1, v1, hkv)
 
-    scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    qs = (q.astype(jnp.float32) * scale).reshape(b, hkv, g, dh)
-
-    kernel = functools.partial(_decode_attn_fused_kernel, window=window,
-                               softcap=softcap, block_k=bk, nk=nk)
-    out = pl.pallas_call(
-        kernel,
+    kernel = functools.partial(_decode_attn_fused_kernel, n_prefetch=1,
+                               window=window, softcap=softcap, nk=nk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, hkv, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi, ki: (bi,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, dh), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bk, 1, dh), lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bk, 1, dh), lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bk), lambda bi, hi, ki: (bi, ki)),
-            pl.BlockSpec((1, 1, dh), lambda bi, hi, ki: (bi, hi, 0)),
-            pl.BlockSpec((1, 1, dh), lambda bi, hi, ki: (bi, hi, 0)),
+            pl.BlockSpec((1, 1, g, dh),
+                         lambda bi, hi, ki, pos_ref: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, bk, dh), lambda bi, hi, ki, pos_ref: (bi, ki, hi)),
+            pl.BlockSpec((1, bk, dh), lambda bi, hi, ki, pos_ref: (bi, ki, hi)),
+            pl.BlockSpec((1, 1, bk), lambda bi, hi, ki, pos_ref: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, dh), lambda bi, hi, ki, pos_ref: (bi, 0, hi)),
+            pl.BlockSpec((1, 1, dh), lambda bi, hi, ki, pos_ref: (bi, 0, hi)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, hi, ki: (bi, hi, 0, 0)),
+                               lambda bi, hi, ki, pos_ref: (bi, hi, 0, 0)),
+        scratch_shapes=_fused_scratch(g, dh),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),        # running max m
-            pltpu.VMEM((g,), jnp.float32),        # running denom l
-            pltpu.VMEM((g, dh), jnp.float32),     # running numerator acc
-        ],
         interpret=interpret,
-    )(pos.astype(jnp.int32), qs, ck, cv, cpos, k1, v1)
+    )(pos.astype(jnp.int32), qs, ck.reshape(b, sc, hkv * dh),
+      cv.reshape(b, sc, hkv * dh), cpos.reshape(b, 1, sc), k1, v1)
     return out.reshape(b, h, dh).astype(q.dtype)

@@ -6,9 +6,15 @@ softmax state (m, l, acc) in VMEM scratch across the (sequential, innermost)
 kv-block grid axis, so scores never leave VMEM.
 
 Grid = (B, Hkv, Sq//bq, Sk//bk) — kv innermost, q-block output revisited.
-Supports GQA (q block [bq, G, Dh] vs kv [bk, Dh]), causal masking, sliding
-windows and score softcap via position operands (same mask semantics as
-``models.attention.blockwise_attention``, its oracle).
+Supports GQA (the G query heads of one kv head vs kv [bk, Dh]), causal
+masking, sliding windows and score softcap via position operands (same mask
+semantics as ``models.attention.blockwise_attention``, its oracle).
+
+TPU block shapes (last two dims divisible by (8, 128) or whole): q, k, v and
+the output are viewed as [B, S, heads*Dh] (free reshapes), so one grid step
+reads the G*Dh lanes of its kv head's query group and the Dh lanes of the kv
+head itself — legal for Dh a multiple of 128, or a single kv head. Query
+positions ride as a [bq, 1] column, key positions as a [1, bk] row.
 """
 from __future__ import annotations
 
@@ -24,7 +30,8 @@ NEG_INF = -1e30
 
 def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
                   m_scr, l_scr, acc_scr,
-                  *, causal: bool, window: int, softcap: float):
+                  *, causal: bool, window: int, softcap: float, g: int,
+                  dh: int):
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -34,46 +41,42 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0].astype(jnp.float32)       # [bq, G, Dh] (pre-scaled)
-    k = k_ref[0, :, 0].astype(jnp.float32)       # [bk, Dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)       # [bk, Dh]
-    qpos = qpos_ref[0]                           # [bq]
-    kpos = kpos_ref[0]                           # [bk]
-
-    bq, g, dh = q.shape
-    s = jax.lax.dot_general(q.reshape(bq * g, dh), k,
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s.reshape(bq, g, -1)                     # [bq, G, bk]
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    mask = kpos[None, :] >= 0
+    k = k_ref[0].astype(jnp.float32)             # [bk, Dh]
+    v = v_ref[0].astype(jnp.float32)             # [bk, Dh]
+    qpos = qpos_ref[0]                           # [bq, 1]
+    kpos = kpos_ref[0]                           # [1, bk]
+    mask = kpos >= 0
     if causal:
-        mask &= kpos[None, :] <= qpos[:, None]
+        mask &= kpos <= qpos
     if window:
-        mask &= kpos[None, :] > qpos[:, None] - window
-    s = jnp.where(mask[:, None, :], s, NEG_INF)
+        mask &= kpos > qpos - window             # [bq, bk]
 
-    m_prev, l_prev, acc_prev = m_scr[...], l_scr[...], acc_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[..., None])
-    p = jnp.where(mask[:, None, :], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1)
-    pv = jax.lax.dot_general(p.reshape(bq * g, -1), v,
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_new = acc_prev * corr[..., None] + pv.reshape(bq, g, dh)
+    for gi in range(g):                          # query heads of this kv head
+        lanes = slice(gi * dh, (gi + 1) * dh)
+        q = q_ref[0, :, lanes].astype(jnp.float32)   # [bq, Dh] (pre-scaled)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if softcap:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(mask, s, NEG_INF)          # [bq, bk]
 
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc_new
+        m_prev, l_prev = m_scr[gi], l_scr[gi]    # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc_scr[gi] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[gi] = m_new
+        l_scr[gi] = l_new
+        acc_scr[gi] = acc_new
 
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        out = acc_new / jnp.maximum(l_new[..., None], 1e-30)
-        out = jnp.where((l_new > 0)[..., None], out, 0.0)
-        o_ref[0, :, 0] = out.astype(o_ref.dtype)
+        @pl.when(ki == nk - 1)
+        def _finalize():
+            out = acc_new / jnp.maximum(l_new, 1e-30)
+            out = jnp.where(l_new > 0, out, 0.0)
+            o_ref[0, :, lanes] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
@@ -97,33 +100,32 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal: bool = True,
 
     bq, bk = fit(block_q, sq), fit(block_k, sk)
     scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    qs = (q.astype(jnp.float32) * scale).reshape(b, sq, hkv, g, dh)
-    qs = qs.astype(q.dtype)
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
 
     grid = (b, hkv, sq // bq, sk // bk)
     kernel = functools.partial(_flash_kernel, causal=causal, window=window,
-                               softcap=softcap)
+                               softcap=softcap, g=g, dh=dh)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, g, dh),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0, 0)),
-            pl.BlockSpec((1, bk, 1, dh),
-                         lambda bi, hi, qi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bk, 1, dh),
-                         lambda bi, hi, qi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bq), lambda bi, hi, qi, ki: (bi, qi)),
-            pl.BlockSpec((1, bk), lambda bi, hi, qi, ki: (bi, ki)),
+            pl.BlockSpec((1, bq, g * dh),
+                         lambda bi, hi, qi, ki: (bi, qi, hi)),
+            pl.BlockSpec((1, bk, dh), lambda bi, hi, qi, ki: (bi, ki, hi)),
+            pl.BlockSpec((1, bk, dh), lambda bi, hi, qi, ki: (bi, ki, hi)),
+            pl.BlockSpec((1, bq, 1), lambda bi, hi, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, bk), lambda bi, hi, qi, ki: (bi, 0, ki)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, g, dh),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, hkv, g, dh), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, g * dh),
+                               lambda bi, hi, qi, ki: (bi, qi, hi)),
+        out_shape=jax.ShapeDtypeStruct((b, sq, h * dh), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq, g), jnp.float32),
-            pltpu.VMEM((bq, g), jnp.float32),
-            pltpu.VMEM((bq, g, dh), jnp.float32),
+            pltpu.VMEM((g, bq, 1), jnp.float32),
+            pltpu.VMEM((g, bq, 1), jnp.float32),
+            pltpu.VMEM((g, bq, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(qs, k, v, q_pos.astype(jnp.int32), k_pos.astype(jnp.int32))
+    )(qs.reshape(b, sq, h * dh), k.reshape(b, sk, hkv * dh),
+      v.reshape(b, sk, hkv * dh), q_pos.astype(jnp.int32).reshape(b, sq, 1),
+      k_pos.astype(jnp.int32).reshape(b, 1, sk))
     return out.reshape(b, sq, h, dh)

@@ -50,21 +50,35 @@ def _moe_ffn_kernel(counts_ref, x_ref, wg_ref, wu_ref, wd_ref, y_ref,
 
 def _moe_ffn_body(x_ref, wg_ref, wu_ref, wd_ref, y_ref, *, act: str,
                   gated: bool):
-    x = x_ref[0].astype(jnp.float32)             # [bc, D]
-    wu = wu_ref[0].astype(jnp.float32)           # [D, bf]
-    up = jax.lax.dot_general(x, wu, (((1,), (0,)), ((), ())),
+    # operands enter the MXU in their stored dtype (bf16 on the chip) and
+    # accumulate in f32: no weight tile is upcast in VMEM
+    x = x_ref[0]                                 # [bc, D]
+    up = jax.lax.dot_general(x, wu_ref[0], (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     fn = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[act]
     if gated:
-        wg = wg_ref[0].astype(jnp.float32)
-        gate = jax.lax.dot_general(x, wg, (((1,), (0,)), ((), ())),
+        gate = jax.lax.dot_general(x, wg_ref[0], (((1,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32)
         hidden = fn(gate) * up
     else:
         hidden = fn(up)
-    wd = wd_ref[0].astype(jnp.float32)           # [bf, D]
-    y_ref[0] += jax.lax.dot_general(hidden, wd, (((1,), (0,)), ((), ())),
+    wd = wd_ref[0]                               # [bf, D]
+    y_ref[0] += jax.lax.dot_general(hidden.astype(wd.dtype), wd,
+                                    (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
+
+
+# Scoped VMEM the pipelined tiles may take: v5e's default scoped limit is
+# 16 MiB; 4 MiB stay free for the kernel's own temporaries.
+VMEM_TILE_BUDGET = 12 * 2**20
+
+
+def _tile_bytes(bc: int, bf: int, d: int, x_bytes: int,
+                w_bytes: int) -> int:
+    """Double-buffered VMEM of one grid step: the x tile, the three weight
+    tiles of [D, bf] / [bf, D] (an ungated FFN's placeholder gate is
+    pipelined too), and the f32 output tile."""
+    return 2 * (bc * d * x_bytes + 3 * d * bf * w_bytes + bc * d * 4)
 
 
 @functools.partial(jax.jit, static_argnames=("act", "block_c", "block_f",
@@ -84,12 +98,14 @@ def moe_gemm(x, w_gate, w_up, w_down, *, counts=None, act: str = "silu",
     while c % bc:
         bc //= 2
     bc = max(bc, 1)
-    bf = min(block_f, f)
-    while f % bf:
-        bf //= 2
-    bf = max(bf, 1)
-
     gated = w_gate is not None
+    # largest ff tile that divides F and whose double-buffered tiles fit
+    # the scoped VMEM budget (Mixtral bf16, bc=128: bf=128 takes 12 MiB)
+    bf = min(block_f, f)
+    while bf > 1 and (f % bf or _tile_bytes(
+            bc, bf, d, x.dtype.itemsize,
+            w_up.dtype.itemsize) > VMEM_TILE_BUDGET):
+        bf //= 2
     kernel = functools.partial(_moe_ffn_kernel, act=act, gated=gated)
     if not gated:
         w_gate = w_up  # placeholder operand, never read

@@ -9,10 +9,9 @@ CPU-functional mode (default — this container):
 
 The reduced model runs for real; failures are injected and recovered, and
 the EW pool is elastic: scale events, load-aware rebalancing, and shadow
-promotion are versioned placement-plan installs (core/placement.py). On a
-real TPU cluster the same engine/step functions run with the production
-mesh shardings from launch/sharding.py (see launch/dryrun.py for the exact
-jit configuration per architecture x shape).
+promotion are versioned placement-plan installs (core/placement.py).
+``chip_smoke.py`` at the repository root drives the same ``build_server`` +
+``serve`` path on one TPU at Mixtral-8x7B widths.
 """
 from __future__ import annotations
 
@@ -25,6 +24,7 @@ import jax
 from repro.configs import get_config
 from repro.core.orchestrator import Orchestrator
 from repro.data.workloads import make_workload
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.engine import EngineConfig, InferenceEngine
 from repro.serving.scheduler import FailurePlan, ScalePlan, run_serving
 from repro.serving.telemetry import pct
@@ -44,6 +44,33 @@ def parse_scale(s: str) -> ScalePlan:
         raise ValueError(f"unknown scale kind {kind!r} in --scale {s!r} "
                          "(add_ew@T | drain_ew:ID@T | rebalance@T)")
     return ScalePlan(float(t), kind, int(wid) if wid else -1)
+
+
+def build_server(cfg, ecfg: EngineConfig, *, seed: int = 0,
+                 ew_policy: str = "revive", rebalance: bool = False):
+    """The serving stack: one InferenceEngine (Gateway, AWs and EWs, the
+    ERT/shadow-slot datapath, the checkpoint store) under an Orchestrator.
+
+    MoE layers serve at capacity factor 4.0: no expert slot overflows, so
+    the tokens a failure masks cannot change how the surviving requests'
+    tokens are routed, and token streams stay identical across failures.
+    Returns (engine, orchestrator)."""
+    if cfg.moe.enabled:
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=4.0))
+    eng = InferenceEngine(cfg, ecfg, jax.random.PRNGKey(seed))
+    orch = Orchestrator(eng, worker_init_time=1.0, weight_push_time=0.25,
+                        ew_policy=ew_policy, auto_rebalance=rebalance)
+    return eng, orch
+
+
+def serve(eng: InferenceEngine, orch: Orchestrator, workload, *,
+          failures=(), scales=()):
+    """Drive ``workload`` through the serving loop on its virtual clock
+    (fixed 50 ms decode ticks, so failure injection is deterministic)."""
+    return run_serving(eng, workload, duration=600.0, orchestrator=orch,
+                       failures=list(failures), scale_events=list(scales),
+                       step_time=0.05)
 
 
 def main():
@@ -119,10 +146,8 @@ def main():
     if args.prefix_slots and not args.chunk_budget:
         args.chunk_budget = 16
 
+    use_compile_cache()
     cfg = get_config(args.arch).reduced()
-    if cfg.moe.enabled:
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=4.0))
     if args.workload == "multi_turn_chat" and \
             args.placement == "least_loaded":
         args.placement = "session_affinity"
@@ -144,17 +169,15 @@ def main():
                         victim_policy="controller" if args.controller and
                         not args.no_preempt else "remaining_work",
                         watchdogs=args.watchdogs)
-    eng = InferenceEngine(cfg, ecfg, jax.random.PRNGKey(args.seed))
-    orch = Orchestrator(eng, worker_init_time=1.0, weight_push_time=0.25,
-                        ew_policy=args.ew_policy,
-                        auto_rebalance=args.rebalance)
+    eng, orch = build_server(cfg, ecfg, seed=args.seed,
+                             ew_policy=args.ew_policy,
+                             rebalance=args.rebalance)
 
     wl = make_workload(args.workload, args.rps, args.duration,
                        seed=args.seed, max_prompt=16, max_new=24)
-    failures = [parse_failure(f) for f in args.fail]
-    scales = [parse_scale(s) for s in args.scale]
-    m = run_serving(eng, wl, duration=600.0, orchestrator=orch,
-                    failures=failures, scale_events=scales, step_time=0.05)
+    m = serve(eng, orch, wl,
+              failures=[parse_failure(f) for f in args.fail],
+              scales=[parse_scale(s) for s in args.scale])
 
     tbt = m.tbt_values()
     print(f"[serve] {cfg.name} tarragon={not args.no_tarragon} "

@@ -206,8 +206,11 @@ class ContinuousBatchScheduler:
         self.stats.padded_tokens += rows * length
         self.stats.batch_sizes.append(n_real)
 
+        # the prefill builds a contiguous per-request cache whatever the
+        # engine's layout: its rows are read with the contiguous layout
+        prefill_layout = getattr(eng.layout, "inner", eng.layout)
         for i, (q, aw, slot) in enumerate(entries):
-            state = eng.layout.request_state(req_cache, i)
+            state = prefill_layout.request_state(req_cache, i)
             if padded and pre_lens[i] < length:
                 state = eng.layout.scrub_request_state(state, pre_lens[i])
             # paged engines map pages covering the prefilled prefix before

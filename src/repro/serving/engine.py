@@ -286,7 +286,9 @@ class InferenceEngine:
         self.init_key_data = flightrec.key_host_data(key)
         self.api = get_model(cfg, num_aw=ecfg.num_aw, num_ew=ecfg.num_ew,
                              tarragon=ecfg.tarragon)
-        self.params = self.api.init_params(key)
+        # jitted, so every leaf is drawn straight into the model dtype: the
+        # cast fuses with the draw and no float32 copy of the tree is live
+        self.params = jax.jit(self.api.init_params)(key)
         self.route_state: RouteState = self.api.init_route_state()
         # ---- KV plane: contiguous per-slot cache, or paged block tables ---
         # Paged mode (kv_page_tokens > 0) swaps the layout, not the model:

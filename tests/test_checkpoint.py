@@ -34,12 +34,28 @@ def test_out_of_order_waits_for_gap():
     s.async_update("r", 2, _seg(2), seqs[2], 102)   # seq 1 missing
     s.async_update("r", 3, _seg(3), seqs[3], 103)
     assert s.committed_token("r") == 0
+    assert s.stats.out_of_order >= 2
     c, tv, segs = s.restore_request("r")
     assert c == 0 and sorted(segs) == [0]
-    # gap fills -> watermark jumps over the whole contiguous range
+    # restoration truncated the log to the watermark: seqs 2-3 are gone, so
+    # filling the gap commits only token 1
+    s.async_update("r", 1, _seg(1), seqs[1], 101)
+    assert s.committed_token("r") == 1
+    c, tv, segs = s.restore_request("r")
+    assert c == 1 and tv == 101 and sorted(segs) == [0, 1]
+
+
+def test_gap_fill_commits_contiguous_range():
+    """Without a restore in between, filling the gap moves the watermark
+    over the whole contiguous range that arrived early."""
+    s = CheckpointStore()
+    s.register_request("r", aw_id=0)
+    seqs = [s.next_seq("r") for _ in range(4)]
+    for t in (0, 2, 3):
+        s.async_update("r", t, _seg(t), seqs[t], 100 + t)
+    assert s.committed_token("r") == 0
     s.async_update("r", 1, _seg(1), seqs[1], 101)
     assert s.committed_token("r") == 3
-    assert s.stats.out_of_order >= 2
 
 
 @given(st.permutations(list(range(8))))

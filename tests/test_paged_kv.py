@@ -228,6 +228,24 @@ def test_paged_matches_contiguous_under_aw_failure(seg_len):
     assert results["paged"] == results["contig"]
 
 
+def test_paged_whole_prompt_prefill_matches_contiguous():
+    """Without the chunked plane, prompts take the whole-prompt (padded)
+    prefill, whose per-request cache is contiguous: a paged engine
+    scatters it into pages and emits the contiguous engine's tokens."""
+    results = {}
+    for mode, kw in [("contig", {}), ("paged", dict(kv_page_tokens=16))]:
+        eng = make_engine(chunk_token_budget=0, prefix_cache_slots=0, **kw)
+        hs = [eng.client.submit(RequestSpec(
+            rid=f"w{i}", prompt=np.random.default_rng(200 + i).integers(
+                1, 200, size=(9 + 11 * i,)).astype(np.int32), max_new=5))
+            for i in range(3)]
+        drain(eng, hs)
+        if eng.pages is not None:
+            eng.pages.check()
+        results[mode] = [list(h.tokens()) for h in hs]
+    assert results["paged"] == results["contig"]
+
+
 def test_paged_zero_new_traces():
     """The whole paged lifecycle — cold admission, warm prefix hits,
     AW failover + restoration — re-uses the first-turn jit traces: block
