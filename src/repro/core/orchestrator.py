@@ -28,6 +28,7 @@ from dataclasses import dataclass
 from typing import List, Optional
 
 from repro.core.costmodel import TarragonProfile
+from repro.serving.telemetry import span
 
 
 @dataclass
@@ -100,6 +101,12 @@ class Orchestrator:
         fr = getattr(engine, "flightrec", None)
         if fr is not None:
             fr.note_orchestrator(self)
+
+    def _span(self, name: str, **args):
+        """A host span on the engine's telemetry plane, track
+        ``recovery``."""
+        return span(getattr(self.engine, "telemetry", None), "recovery",
+                    name, **args)
 
     def _emit(self, ev: WorkerEvent):
         self.events.append(ev)
@@ -175,6 +182,53 @@ class Orchestrator:
             self._last_rebalance = now
             self.request_rebalance(now)
 
+    def _handle_detection(self, f: _PendingFailure,
+                          now: float) -> WorkerEvent:
+        """Self-healing for one failure detected at ``now``."""
+        ev = WorkerEvent(now, "detected", f"{f.kind}{f.worker_id}")
+        tel = getattr(self.engine, "telemetry", None)
+        if tel is not None:
+            # the detection window [t_fail, now] is the T_w component
+            # of every stall this failure causes
+            tel.on_failure_detected(f.kind, f.worker_id, f.t_fail, now)
+        if f.kind == "ew":
+            # AW-side self-healing: ERT remap to shadows (instant once
+            # detected)
+            with self._span("recovery.ew_remap"):
+                self.engine.fail_ew(f.worker_id)
+                promote = self.ew_policy == "promote" and \
+                    self.engine.placement_mgr is not None
+                if promote:
+                    # permanent promotion: pool shrinks, shadows become
+                    # primaries now; fresh replicas for the most critical
+                    # survivor land after the background weight push
+                    self.engine.promote_shadows(f.worker_id, now=now)
+            if promote:
+                ev.detail = "shadows promoted to primaries (pool -1)"
+                self._provisions.append(_PendingProvision(
+                    "reprotect", f.worker_id, now + self.T_push))
+            else:
+                ev.detail = "ERT remap -> shadow experts"
+                self._provisions.append(
+                    _PendingProvision(f.kind, f.worker_id,
+                                      now + self.T_w))
+        else:
+            # EW-side self-healing: health mask drops the AW's slots;
+            # per-request restoration re-admits its requests through
+            # the Gateway (unplaceable ones stay queued and retry); each
+            # restore is a recovery.restore span inside this one
+            with self._span("recovery.aw_requeue"):
+                self.engine.fail_aw(f.worker_id)
+                n = len(self.engine.recover_aw_requests(now=now))
+            ev.detail = f"restored {n} requests"
+            waiting = self.engine.gateway.depth()
+            if waiting:
+                ev.detail += f" ({waiting} queued for retry)"
+            self._provisions.append(
+                _PendingProvision(f.kind, f.worker_id, now + self.T_w))
+        self._emit(ev)
+        return ev
+
     # -- control loop --------------------------------------------------------
     def tick(self, now: float) -> List[WorkerEvent]:
         """Advance the control plane to virtual time ``now``. Returns the
@@ -184,44 +238,9 @@ class Orchestrator:
             if f.detected or now < f.t_fail + self.detection_latency():
                 continue
             f.detected = True
-            ev = WorkerEvent(now, "detected", f"{f.kind}{f.worker_id}")
-            tel = getattr(self.engine, "telemetry", None)
-            if tel is not None:
-                # the detection window [t_fail, now] is the T_w component
-                # of every stall this failure causes
-                tel.on_failure_detected(f.kind, f.worker_id, f.t_fail, now)
-            if f.kind == "ew":
-                # AW-side self-healing: ERT remap to shadows (instant once
-                # detected)
-                self.engine.fail_ew(f.worker_id)
-                if self.ew_policy == "promote" and \
-                        self.engine.placement_mgr is not None:
-                    # permanent promotion: pool shrinks, shadows become
-                    # primaries now; fresh replicas for the most critical
-                    # survivor land after the background weight push
-                    self.engine.promote_shadows(f.worker_id, now=now)
-                    ev.detail = "shadows promoted to primaries (pool -1)"
-                    self._provisions.append(_PendingProvision(
-                        "reprotect", f.worker_id, now + self.T_push))
-                else:
-                    ev.detail = "ERT remap -> shadow experts"
-                    self._provisions.append(
-                        _PendingProvision(f.kind, f.worker_id,
-                                          now + self.T_w))
-            else:
-                # EW-side self-healing: health mask drops the AW's slots;
-                # per-request restoration re-admits its requests through
-                # the Gateway (unplaceable ones stay queued and retry).
-                self.engine.fail_aw(f.worker_id)
-                n = len(self.engine.recover_aw_requests(now=now))
-                ev.detail = f"restored {n} requests"
-                waiting = self.engine.gateway.depth()
-                if waiting:
-                    ev.detail += f" ({waiting} queued for retry)"
-                self._provisions.append(
-                    _PendingProvision(f.kind, f.worker_id, now + self.T_w))
-            self._emit(ev)
-            fired.append(ev)
+            with self._span("recovery.detect", kind=f.kind,
+                            worker=f.worker_id):
+                fired.append(self._handle_detection(f, now))
 
         remaining = []
         for p in self._provisions:

@@ -238,29 +238,34 @@ def expert_io(x, routing, expert_fn):
     t, d = x.shape
     p, c = routing["num_slots"], routing["capacity"]
     if not routing["grouped"]:
-        dispatch, combine = routing_onehots(routing)
-        expert_in = jnp.einsum("tpc,td->pcd", dispatch.astype(x.dtype), x)
+        with jax.named_scope("dispatch"):
+            dispatch, combine = routing_onehots(routing)
+            expert_in = jnp.einsum("tpc,td->pcd", dispatch.astype(x.dtype),
+                                   x)
         expert_out = expert_fn(expert_in)
-        return jnp.einsum("tpc,pcd->td", combine.astype(expert_out.dtype),
-                          expert_out)
+        with jax.named_scope("combine"):
+            return jnp.einsum("tpc,pcd->td",
+                              combine.astype(expert_out.dtype), expert_out)
 
     # GShard-style grouped dispatch: groups ride the data axis, slots the
     # model axis -> expert compute is 2D-sharded, combine psums over slots.
-    g, s_g = routing["groups"], routing["group_size"]
-    k = routing["slot_idx"].shape[1]
-    slot_oh = jax.nn.one_hot(
-        routing["slot_idx"].reshape(g, s_g, k), p, dtype=x.dtype)
-    slot_oh = slot_oh * routing["keep"].reshape(
-        g, s_g, k, 1).astype(x.dtype)
-    pos_oh = jax.nn.one_hot(
-        routing["pos"].reshape(g, s_g, k), c, dtype=x.dtype)
-    dispatch = jnp.einsum("gskp,gskc->gspc", slot_oh, pos_oh)
-    combine = jnp.einsum(
-        "gskp,gskc->gspc",
-        slot_oh * routing["gate_w"].reshape(g, s_g, k, 1).astype(x.dtype),
-        pos_oh)
-    xg = x.reshape(g, s_g, d)
-    expert_in = jnp.einsum("gspc,gsd->pgcd", dispatch, xg)   # [P,G,C,D]
+    with jax.named_scope("dispatch"):
+        g, s_g = routing["groups"], routing["group_size"]
+        k = routing["slot_idx"].shape[1]
+        slot_oh = jax.nn.one_hot(
+            routing["slot_idx"].reshape(g, s_g, k), p, dtype=x.dtype)
+        slot_oh = slot_oh * routing["keep"].reshape(
+            g, s_g, k, 1).astype(x.dtype)
+        pos_oh = jax.nn.one_hot(
+            routing["pos"].reshape(g, s_g, k), c, dtype=x.dtype)
+        dispatch = jnp.einsum("gskp,gskc->gspc", slot_oh, pos_oh)
+        combine = jnp.einsum(
+            "gskp,gskc->gspc",
+            slot_oh * routing["gate_w"].reshape(g, s_g, k, 1).astype(x.dtype),
+            pos_oh)
+        xg = x.reshape(g, s_g, d)
+        expert_in = jnp.einsum("gspc,gsd->pgcd", dispatch, xg)   # [P,G,C,D]
     expert_out = expert_fn(expert_in)
-    y = jnp.einsum("gspc,pgcd->gsd", combine, expert_out)
-    return y.reshape(t, d)
+    with jax.named_scope("combine"):
+        y = jnp.einsum("gspc,pgcd->gsd", combine, expert_out)
+        return y.reshape(t, d)

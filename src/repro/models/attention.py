@@ -313,13 +313,15 @@ def attn_full(cfg: ModelConfig, params, x, positions, *, window: int = 0,
     k = apply_rope(k, positions, cfg.rope_theta)
     from repro.kernels import ops as kops
     bk = _pick_block(k.shape[1], PREFILL_BLOCK_K) if cache is not None else 0
-    out = kops.full_attention(
-        q, k, v, positions, positions, window=window,
-        softcap=cfg.attn_softcap, causal=causal, block_k=bk)
+    with jax.named_scope("attention"):
+        out = kops.full_attention(
+            q, k, v, positions, positions, window=window,
+            softcap=cfg.attn_softcap, causal=causal, block_k=bk)
     out = out.reshape(*x.shape[:2], -1) @ params["wo"]
     new_cache = None
     if cache is not None:
-        new_cache = cache_write_prefill(cache, k, v, positions)
+        with jax.named_scope("kv_write"):
+            new_cache = cache_write_prefill(cache, k, v, positions)
     return out, new_cache
 
 
@@ -335,12 +337,14 @@ def attn_chunk(cfg: ModelConfig, params, x, cache, positions, *,
     k, v = _project_kv(cfg, params, x)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    new_cache = cache_write_chunk(cache, k, v, positions)
+    with jax.named_scope("kv_write"):
+        new_cache = cache_write_chunk(cache, k, v, positions)
     from repro.kernels import ops as kops
-    out = kops.full_attention(
-        q, new_cache["k"], new_cache["v"], positions, new_cache["pos"],
-        window=window, softcap=cfg.attn_softcap, causal=True,
-        block_k=_pick_block(new_cache["k"].shape[1], PREFILL_BLOCK_K))
+    with jax.named_scope("attention"):
+        out = kops.full_attention(
+            q, new_cache["k"], new_cache["v"], positions, new_cache["pos"],
+            window=window, softcap=cfg.attn_softcap, causal=True,
+            block_k=_pick_block(new_cache["k"].shape[1], PREFILL_BLOCK_K))
     out = out.reshape(*x.shape[:2], -1) @ params["wo"]
     return out, new_cache
 
@@ -355,13 +359,15 @@ def attn_chunk_paged(cfg: ModelConfig, params, x, cache, bt, positions):
     k, v = _project_kv(cfg, params, x)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    new_cache = paged_write_chunk(cache, bt, k, v, positions)
-    view = paged_view(new_cache, bt)
+    with jax.named_scope("kv_write"):
+        new_cache = paged_write_chunk(cache, bt, k, v, positions)
     from repro.kernels import ops as kops
-    out = kops.full_attention(
-        q, view["k"], view["v"], positions, view["pos"],
-        window=0, softcap=cfg.attn_softcap, causal=True,
-        block_k=_pick_block(view["k"].shape[1], PREFILL_BLOCK_K))
+    with jax.named_scope("attention"):
+        view = paged_view(new_cache, bt)
+        out = kops.full_attention(
+            q, view["k"], view["v"], positions, view["pos"],
+            window=0, softcap=cfg.attn_softcap, causal=True,
+            block_k=_pick_block(view["k"].shape[1], PREFILL_BLOCK_K))
     out = out.reshape(*x.shape[:2], -1) @ params["wo"]
     return out, new_cache
 
@@ -377,11 +383,13 @@ def attn_decode_paged(cfg: ModelConfig, params, x, cache, bt, pos):
     q = apply_rope(q, pos[:, None], cfg.rope_theta)
     k1 = apply_rope(k1, pos[:, None], cfg.rope_theta)
     from repro.kernels import ops as kops
-    out = kops.decode_attention_paged(
-        q[:, 0], cache["k"], cache["v"], cache["pos"], bt,
-        k1[:, 0], v1[:, 0], pos, softcap=cfg.attn_softcap)
+    with jax.named_scope("attention"):
+        out = kops.decode_attention_paged(
+            q[:, 0], cache["k"], cache["v"], cache["pos"], bt,
+            k1[:, 0], v1[:, 0], pos, softcap=cfg.attn_softcap)
     out = out.reshape(b, 1, -1) @ params["wo"]
-    new_cache = paged_write_token(cache, bt, k1, v1, pos)
+    with jax.named_scope("kv_write"):
+        new_cache = paged_write_token(cache, bt, k1, v1, pos)
     return out, new_cache
 
 
@@ -398,12 +406,14 @@ def attn_decode(cfg: ModelConfig, params, x, cache, pos, *, window: int = 0):
     k1 = apply_rope(k1, pos[:, None], cfg.rope_theta)
 
     from repro.kernels import ops as kops
-    out = kops.decode_attention(
-        q[:, 0], cache["k"], cache["v"], cache["pos"],
-        k1[:, 0], v1[:, 0], pos,
-        window=window, softcap=cfg.attn_softcap)
+    with jax.named_scope("attention"):
+        out = kops.decode_attention(
+            q[:, 0], cache["k"], cache["v"], cache["pos"],
+            k1[:, 0], v1[:, 0], pos,
+            window=window, softcap=cfg.attn_softcap)
     out = out.reshape(b, 1, -1) @ params["wo"]
-    new_cache = cache_write_token(cache, k1, v1, pos, window=window)
+    with jax.named_scope("kv_write"):
+        new_cache = cache_write_token(cache, k1, v1, pos, window=window)
     return out, new_cache
 
 

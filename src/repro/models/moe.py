@@ -71,25 +71,27 @@ def moe_apply(cfg: ModelConfig, params, x, route_state: refe.RouteState,
     """
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
-    logits = xt @ params["router"].astype(xt.dtype)
-
-    routing = refe.route(
-        xt, logits, route_state, placement,
-        top_k=cfg.moe.top_k, capacity_factor=cfg.moe.capacity_factor,
-        capacity=capacity, batch=b,
-        token_mask=None if token_mask is None
-        else token_mask.reshape(b * s))
+    with jax.named_scope("router"):
+        logits = xt @ params["router"].astype(xt.dtype)
+        routing = refe.route(
+            xt, logits, route_state, placement,
+            top_k=cfg.moe.top_k, capacity_factor=cfg.moe.capacity_factor,
+            capacity=capacity, batch=b,
+            token_mask=None if token_mask is None
+            else token_mask.reshape(b * s))
 
     # physical slot bank, gathered through the plan's slot indirection: any
     # slot (primary, shadow, replica) serves its resident expert's rows —
     # a placement change re-points this without touching the trace
-    bank = shadow_lib.resident_slot_bank(params["experts"],
-                                         route_state.slot_expert)
+    with jax.named_scope("slot_bank_gather"):
+        bank = shadow_lib.resident_slot_bank(params["experts"],
+                                             route_state.slot_expert)
 
     def expert_fn(expert_in):
-        return kops.expert_ffn(expert_in, bank["wg"].astype(x.dtype),
-                               bank["wu"].astype(x.dtype),
-                               bank["wd"].astype(x.dtype), act=cfg.act)
+        with jax.named_scope("expert_ffn"):
+            return kops.expert_ffn(expert_in, bank["wg"].astype(x.dtype),
+                                   bank["wu"].astype(x.dtype),
+                                   bank["wd"].astype(x.dtype), act=cfg.act)
 
     y = refe.expert_io(xt, routing, expert_fn)
 

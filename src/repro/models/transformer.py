@@ -102,31 +102,33 @@ def _layer_apply(cfg: ModelConfig, p, x, *, window: int, mode: str,
     [B, nblk] block table of a paged cache (None = contiguous layout);
     when set, ``cache`` holds physical page pools instead of per-slot
     rows and the paged attention twins are used."""
-    h = rmsnorm(p["ln1"], x, cfg.norm_eps)
-    if mode == "decode" and bt is not None:
-        a, new_cache = attn.attn_decode_paged(cfg, p["attn"], h, cache, bt,
-                                              pos)
-    elif mode == "decode":
-        a, new_cache = attn.attn_decode(cfg, p["attn"], h, cache, pos,
-                                        window=window)
-    elif mode == "chunk" and bt is not None:
-        a, new_cache = attn.attn_chunk_paged(cfg, p["attn"], h, cache, bt,
-                                             positions)
-    elif mode == "chunk":
-        a, new_cache = attn.attn_chunk(cfg, p["attn"], h, cache, positions,
-                                       window=window)
-    else:
-        a, new_cache = attn.attn_full(cfg, p["attn"], h, positions,
-                                      window=window, cache=cache)
+    with jax.named_scope("attn"):
+        h = rmsnorm(p["ln1"], x, cfg.norm_eps)
+        if mode == "decode" and bt is not None:
+            a, new_cache = attn.attn_decode_paged(cfg, p["attn"], h, cache,
+                                                  bt, pos)
+        elif mode == "decode":
+            a, new_cache = attn.attn_decode(cfg, p["attn"], h, cache, pos,
+                                            window=window)
+        elif mode == "chunk" and bt is not None:
+            a, new_cache = attn.attn_chunk_paged(cfg, p["attn"], h, cache,
+                                                 bt, positions)
+        elif mode == "chunk":
+            a, new_cache = attn.attn_chunk(cfg, p["attn"], h, cache,
+                                           positions, window=window)
+        else:
+            a, new_cache = attn.attn_full(cfg, p["attn"], h, positions,
+                                          window=window, cache=cache)
     x = x + a
     h = rmsnorm(p["ln2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     n_slots = placement.num_slots if placement is not None else 0
     load = jnp.zeros((n_slots,), jnp.float32)
     if "moe" in p:
-        f, aux, load = moe_mod.moe_apply(cfg, p["moe"], h, route_state,
-                                         placement, capacity=capacity,
-                                         token_mask=token_mask)
+        with jax.named_scope("moe"):
+            f, aux, load = moe_mod.moe_apply(cfg, p["moe"], h, route_state,
+                                             placement, capacity=capacity,
+                                             token_mask=token_mask)
     else:
         f = mlp(p["mlp"], h, cfg.act)
     return x + f, new_cache, aux, load

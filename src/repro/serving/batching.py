@@ -50,7 +50,9 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.checkpoint import _seg_nbytes
 from repro.serving.gateway import Gateway, QueuedRequest
+from repro.serving.telemetry import span
 
 
 def _next_pow2(n: int) -> int:
@@ -198,6 +200,7 @@ class ContinuousBatchScheduler:
             firsts = eng.decode_plane.sample_rows(
                 last_logits, [q for q, _, _ in entries],
                 [len(q.prompt) - 1 for q, _, _ in entries])
+            eng.note_syncs()
 
         self.stats.calls += 1
         self.stats.requests += n_real
@@ -256,6 +259,8 @@ class ContinuousBatchScheduler:
                 tk = jnp.arange(n_prefilled, dtype=jnp.int32)
                 stacked = [np.asarray(a)
                            for a in eng._extract(eng.cache, slots, tk)]
+                eng.note_syncs()
+                eng.note_checkpoint(n_prefilled, _seg_nbytes(stacked))
                 for t in range(n_prefilled):
                     seg = [a[t] for a in stacked]
                     # token_value = next decode input after position t
@@ -276,16 +281,29 @@ class ContinuousBatchScheduler:
         if r is None:              # released while waiting for recovery
             eng.aws[aw].slots.release(slot)
             return
-        committed, tok_val, segs = eng.store.restore_request(q.rid)
-        eng._kv_clear_slot(slot)
-        if segs:
-            # paged: map pages covering the restored prefix first — the
-            # committed segments then scatter through the block table
-            eng._kv_ensure(slot, max(segs) + 1)
-        cache = eng.cache
-        for t, seg in segs.items():
-            cache = eng.layout.write_token_segment(cache, slot, t, seg)
-        eng.cache = cache
+        tel = eng.telemetry
+        with span(tel, "recovery", "recovery.restore", rid=q.rid) as sp:
+            with span(tel, "recovery", "restore.read"):
+                committed, tok_val, segs = eng.store.restore_request(q.rid)
+            with span(tel, "recovery", "restore.write"):
+                eng._kv_clear_slot(slot)
+                if segs:
+                    # paged: map pages covering the restored prefix first
+                    # — the committed segments then scatter through the
+                    # block table
+                    eng._kv_ensure(slot, max(segs) + 1)
+                cache = eng.cache
+                for t, seg in segs.items():
+                    cache = eng.layout.write_token_segment(cache, slot, t,
+                                                           seg)
+                eng.cache = cache
+            if tel is not None:
+                nbytes = sum(_seg_nbytes(seg) for seg in segs.values())
+                sp.args.update(segments=len(segs), bytes=nbytes)
+                tel.registry.inc("restore.segments", len(segs))
+                tel.registry.inc("restore.bytes", nbytes)
+                tel.registry.inc("restore.cache_writes",
+                                 sum(len(seg) for seg in segs.values()))
 
         r.slot = slot
         r._aw = aw
@@ -335,21 +353,31 @@ class ContinuousBatchScheduler:
         dispatch (1 = per-step cadence). Returns {rid: new_tokens}."""
         eng = self.engine
         t_now = now if now is not None else float(eng.steps)
+        with span(eng.telemetry, "engine", "step"):
+            return self._step(t_now)
+
+    def _step(self, t_now: float) -> Dict[str, List[int]]:
+        eng = self.engine
+        tel = eng.telemetry
         if eng.controller is not None:
             # control-plane decision pass BEFORE admission: scale/rebalance
             # requests land on the orchestrator's virtual clock and the
             # chunk budget is set before this tick's planner slice runs
-            eng.controller.tick(t_now)
+            with span(tel, "engine", "step.hooks"):
+                eng.controller.tick(t_now)
         if self.gateway.depth():
-            self.admit(t_now)
-        eng.check_deadlines(t_now)
-        if eng.flightrec is not None:
-            # forensics plane: drain the bus through the recorder's own
-            # cursor, fingerprint when due, advance the watchdogs —
-            # host-side only, no effect on anything below
-            eng.flightrec.tick(t_now)
+            with span(tel, "engine", "step.admit"):
+                self.admit(t_now)
+        with span(tel, "engine", "step.hooks"):
+            eng.check_deadlines(t_now)
+            if eng.flightrec is not None:
+                # forensics plane: drain the bus through the recorder's
+                # own cursor, fingerprint when due, advance the watchdogs
+                # — host-side only, no effect on anything below
+                eng.flightrec.tick(t_now)
         if eng.chunked is not None:
-            eng.chunked.tick(t_now)
+            with span(tel, "engine", "step.chunk"):
+                eng.chunked.tick(t_now)
         act = eng.active_requests()
         if not act:
             return {}
@@ -362,62 +390,72 @@ class ContinuousBatchScheduler:
         dispatch + device sampling; only the [B] token vector crosses to
         the host — the [B,V] logits never do."""
         eng = self.engine
-        tokens = np.zeros((eng.ecfg.max_batch,), np.int32)
-        # inactive rows carry pos -1: their cache writes are dropped, so a
-        # decode step can never clobber a slot that is mid-chunked-prefill
-        pos = np.full((eng.ecfg.max_batch,), -1, np.int32)
-        for r in act:
-            tokens[r.slot] = r.next_input
-            pos[r.slot] = r.pos
-            # paged: the step writes KV at r.pos — its page must be mapped
-            eng._kv_ensure(r.slot, r.pos + 1)
-        pos_dev = jnp.asarray(pos)
-        if eng.collect_load:
-            logits, eng.cache, load = eng._decode(
-                eng.params, jnp.asarray(tokens), pos_dev, eng.cache,
-                eng.route_state, capacity=eng.decode_capacity,
-                with_load=True)
-            eng.note_dispatch_load(load)
-        else:
-            logits, eng.cache = eng._decode(
-                eng.params, jnp.asarray(tokens), pos_dev, eng.cache,
-                eng.route_state, capacity=eng.decode_capacity)
-        # sampling head stays on device (counter-based, slot-indexed
-        # params); the drain below is the step's one host sync
-        toks = np.asarray(eng.decode_plane.sample(logits, pos_dev))
-        self.gateway.stats.host_syncs += 1
+        tel = eng.telemetry
+        with span(tel, "engine", "step.decode"):
+            tokens = np.zeros((eng.ecfg.max_batch,), np.int32)
+            # inactive rows carry pos -1: their cache writes are dropped,
+            # so a decode step can never clobber a slot that is
+            # mid-chunked-prefill
+            pos = np.full((eng.ecfg.max_batch,), -1, np.int32)
+            for r in act:
+                tokens[r.slot] = r.next_input
+                pos[r.slot] = r.pos
+                # paged: the step writes KV at r.pos — its page must be
+                # mapped
+                eng._kv_ensure(r.slot, r.pos + 1)
+            pos_dev = jnp.asarray(pos)
+            with span(tel, "engine", "decode.device"):
+                if eng.collect_load:
+                    logits, eng.cache, load = eng._decode(
+                        eng.params, jnp.asarray(tokens), pos_dev, eng.cache,
+                        eng.route_state, capacity=eng.decode_capacity,
+                        with_load=True)
+                    eng.note_dispatch_load(load)
+                else:
+                    logits, eng.cache = eng._decode(
+                        eng.params, jnp.asarray(tokens), pos_dev, eng.cache,
+                        eng.route_state, capacity=eng.decode_capacity)
+                # sampling head stays on device (counter-based,
+                # slot-indexed params); only the [B] token vector crosses
+                # to the host — the [B,V] logits never do
+                toks = np.asarray(eng.decode_plane.sample(logits, pos_dev))
+            eng.note_syncs()
+            self.gateway.stats.host_syncs += 1
 
-        ck_reqs = [r for r in act
-                   if eng.ecfg.checkpoint and eng.aws[r.aw].alive]
-        stacked = None
-        if ck_reqs:
-            # single batched device->host gather for all requests' segments
-            slots = jnp.asarray([r.slot for r in ck_reqs], jnp.int32)
-            tk = jnp.asarray([r.pos for r in ck_reqs], jnp.int32)
-            stacked = [np.asarray(a) for a in eng._extract(eng.cache,
-                                                           slots, tk)]
-        ck_index = {r.rid: i for i, r in enumerate(ck_reqs)}
+            out: Dict[str, List[int]] = {}
+            t_log = t_now
+            for r in act:
+                nxt = int(toks[r.slot])
+                r.pos += 1               # decode wrote KV at r.pos - 1
+                r.tokens.append(nxt)
+                r.next_input = nxt
+                if r.t_first_token < 0:
+                    r.t_first_token = t_log
+                out[r.rid] = [nxt]
+                if len(r.tokens) >= r.max_new or \
+                        r.pos >= eng.ecfg.max_seq - 1:
+                    r.done = True
+                    r.t_done = t_log
 
-        out: Dict[str, List[int]] = {}
-        t_log = t_now
-        for r in act:
-            nxt = int(toks[r.slot])
-            written_pos = r.pos          # decode wrote KV at this position
-            r.pos += 1
-            r.tokens.append(nxt)
-            r.next_input = nxt
-            if r.t_first_token < 0:
-                r.t_first_token = t_log
-            out[r.rid] = [nxt]
-            if r.rid in ck_index:
-                seg = [a[ck_index[r.rid]] for a in stacked]
-                eng.aws[r.aw].checkpointer.checkpoint_token(
-                    r.rid, written_pos, seg, token_value=nxt)
-            if len(r.tokens) >= r.max_new or r.pos >= eng.ecfg.max_seq - 1:
-                r.done = True
-                r.t_done = t_log
-        for w in eng.aws:
-            w.checkpointer.flush()
+        with span(tel, "engine", "step.checkpoint"):
+            ck_reqs = [r for r in act
+                       if eng.ecfg.checkpoint and eng.aws[r.aw].alive]
+            if ck_reqs:
+                # single batched device->host gather for all requests'
+                # segments: the KV each one's decode wrote at pos - 1
+                slots = jnp.asarray([r.slot for r in ck_reqs], jnp.int32)
+                tk = jnp.asarray([r.pos - 1 for r in ck_reqs], jnp.int32)
+                with span(tel, "engine", "checkpoint.device"):
+                    stacked = [np.asarray(a)
+                               for a in eng._extract(eng.cache, slots, tk)]
+                eng.note_syncs()
+                eng.note_checkpoint(len(ck_reqs), _seg_nbytes(stacked))
+                for i, r in enumerate(ck_reqs):
+                    eng.aws[r.aw].checkpointer.checkpoint_token(
+                        r.rid, r.pos - 1, [a[i] for a in stacked],
+                        token_value=r.next_input)
+            for w in eng.aws:
+                w.checkpointer.flush()
         eng.steps += 1
         return out
 
@@ -429,43 +467,50 @@ class ContinuousBatchScheduler:
         (§6.1), so segment boundaries ARE checkpoint boundaries — a crash
         mid-segment rewinds at most seg_len tokens via the §6.2 restore."""
         eng = self.engine
+        tel = eng.telemetry
         seg_len = eng.decode_plane.seg_len
-        ring, loads = eng.decode_plane.run_segment(act, seg_len)
-        self.gateway.stats.host_syncs += 1     # the per-segment drain
-        if eng.collect_load:
-            for i in range(seg_len):
-                eng.note_dispatch_load(loads[i])
+        with span(tel, "engine", "step.decode"):
+            with span(tel, "engine", "decode.device"):
+                ring, loads = eng.decode_plane.run_segment(act, seg_len)
+            eng.note_syncs()
+            self.gateway.stats.host_syncs += 1     # the per-segment drain
+            if eng.collect_load:
+                for i in range(seg_len):
+                    eng.note_dispatch_load(loads[i])
 
-        out: Dict[str, List[int]] = {}
-        max_seq = eng.ecfg.max_seq
-        ck_items = []
-        for r in act:
-            # the device stop mask and this count are the same formula:
-            # steps until max_new or the cache ceiling, capped by seg_len
-            n_take = max(0, min(seg_len, r.max_new - len(r.tokens),
-                                (max_seq - 1) - r.pos))
-            col = ring[:, r.slot]
-            start = r.pos
-            toks = [int(c) for c in col[:n_take]]
-            assert all(c >= 0 for c in toks), \
-                f"{r.rid}: ring drained an inactive step"
-            for nxt in toks:
-                r.pos += 1
-                r.tokens.append(nxt)
-                r.next_input = nxt
-            if toks and r.t_first_token < 0:
-                r.t_first_token = t_now
-            out[r.rid] = toks
-            if toks and eng.ecfg.checkpoint and eng.aws[r.aw].alive:
-                ck_items.append((r, start, len(toks)))
-            if len(r.tokens) >= r.max_new or r.pos >= max_seq - 1:
-                r.done = True
-                r.t_done = t_now
-        if ck_items:
-            # checkpoint_range over exactly the segment's KV writes — one
-            # multi-slot device gather for every request in the segment
-            eng._bulk_checkpoint_group(ck_items)
-        for w in eng.aws:
-            w.checkpointer.flush()
+            out: Dict[str, List[int]] = {}
+            max_seq = eng.ecfg.max_seq
+            ck_items = []
+            for r in act:
+                # the device stop mask and this count are the same
+                # formula: steps until max_new or the cache ceiling,
+                # capped by seg_len
+                n_take = max(0, min(seg_len, r.max_new - len(r.tokens),
+                                    (max_seq - 1) - r.pos))
+                col = ring[:, r.slot]
+                start = r.pos
+                toks = [int(c) for c in col[:n_take]]
+                assert all(c >= 0 for c in toks), \
+                    f"{r.rid}: ring drained an inactive step"
+                for nxt in toks:
+                    r.pos += 1
+                    r.tokens.append(nxt)
+                    r.next_input = nxt
+                if toks and r.t_first_token < 0:
+                    r.t_first_token = t_now
+                out[r.rid] = toks
+                if toks and eng.ecfg.checkpoint and eng.aws[r.aw].alive:
+                    ck_items.append((r, start, len(toks)))
+                if len(r.tokens) >= r.max_new or r.pos >= max_seq - 1:
+                    r.done = True
+                    r.t_done = t_now
+        with span(tel, "engine", "step.checkpoint"):
+            if ck_items:
+                # checkpoint_range over exactly the segment's KV writes —
+                # one multi-slot device gather for every request in the
+                # segment
+                eng._bulk_checkpoint_group(ck_items)
+            for w in eng.aws:
+                w.checkpointer.flush()
         eng.steps += 1
         return out

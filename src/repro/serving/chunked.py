@@ -47,6 +47,8 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serving.telemetry import span
+
 
 def _pow2_at_least(n: int) -> int:
     p = 1
@@ -270,15 +272,20 @@ class ChunkedPrefillPlane:
         # health must not mask its tokens; EW health still applies
         rs_pre = eng.route_state._replace(
             aw_health=jnp.ones_like(eng.route_state.aw_health))
-        if eng.collect_load:
-            eng.cache, load = eng._prefill_chunk(
-                eng.params, jnp.asarray(toks), jnp.asarray(pos), eng.cache,
-                rs_pre, capacity=eng.prefill_capacity(real), with_load=True)
-            eng.note_dispatch_load(load)
-        else:
-            eng.cache = eng._prefill_chunk(
-                eng.params, jnp.asarray(toks), jnp.asarray(pos), eng.cache,
-                rs_pre, capacity=eng.prefill_capacity(real))
+        with span(eng.telemetry, "engine", "chunk.device"):
+            if eng.collect_load:
+                eng.cache, load = eng._prefill_chunk(
+                    eng.params, jnp.asarray(toks), jnp.asarray(pos),
+                    eng.cache, rs_pre, capacity=eng.prefill_capacity(real),
+                    with_load=True)
+                eng.note_dispatch_load(load)
+            else:
+                eng.cache = eng._prefill_chunk(
+                    eng.params, jnp.asarray(toks), jnp.asarray(pos),
+                    eng.cache, rs_pre, capacity=eng.prefill_capacity(real))
+            # the chunk's KV drain: each stream's new segments
+            segs = [self._chunk_segments(job, take, shape)
+                    for job, take in entries]
 
         self.stats.calls += 1
         self.stats.chunks += len(entries)
@@ -287,10 +294,12 @@ class ChunkedPrefillPlane:
         if shape not in self.stats.shapes:
             self.stats.shapes.append(shape)
 
-        for job, take in entries:
+        for (job, take), seg_stack in zip(entries, segs):
             r = eng.requests[job.rid]
             c = r.prefill_cursor
-            self._checkpoint_chunk(job, c, take, shape)
+            if seg_stack is not None:
+                eng._ck_range(eng.aws[job.aw].checkpointer, job.rid, c,
+                              seg_stack, list(job.prompt[c + 1:c + take + 1]))
             r.prefill_cursor = c + take
             eng.aws[job.aw].prefills[job.rid] = r.prefill_cursor
             self.stats.prefilled_tokens[job.rid] = \
@@ -305,22 +314,21 @@ class ChunkedPrefillPlane:
                 self._finalize(r)
         return real
 
-    def _checkpoint_chunk(self, job: _PrefillJob, start: int, take: int,
-                          shape: int):
-        """Stream the chunk's KV segments through the bulk path. The
+    def _chunk_segments(self, job: _PrefillJob, take: int, shape: int):
+        """The chunk's ``take`` new KV segments of ``job``'s slot, one host
+        array per cache leaf (None where checkpointing is off). The
         extractor's static count is the chunk *shape* (bounding jit keys);
-        the real ``take`` segments are sliced out host-side."""
+        the real segments are sliced out host-side."""
         eng = self.engine
         if not eng.ecfg.checkpoint:
-            return
-        sc = eng.ecfg.max_seq
-        base = min(start, sc - shape)          # keep the slice in bounds
+            return None
+        start = eng.requests[job.rid].prefill_cursor
+        base = min(start, eng.ecfg.max_seq - shape)   # keep it in bounds
         seg_stack = [np.asarray(a)[start - base:start - base + take]
                      for a in self._extract_range(eng.cache, job.slot, base,
                                                   count=shape)]
-        token_values = job.prompt[start + 1:start + take + 1]
-        eng._ck_range(eng.aws[job.aw].checkpointer,
-                      job.rid, start, seg_stack, list(token_values))
+        eng.note_syncs()
+        return seg_stack
 
     def _finalize(self, r):
         """Prefill complete: hand the request to the decode plane. Like

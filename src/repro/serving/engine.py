@@ -37,7 +37,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import selfheal
-from repro.core.checkpoint import CheckpointStore
+from repro.core.checkpoint import CheckpointStore, _seg_nbytes
 from repro.core.orchestrator import WorkerEvent
 from repro.core.placement import ExpertPlacementManager, PlacementPlan
 from repro.core.refe import RouteState
@@ -52,7 +52,7 @@ from repro.serving.decode_loop import DecodeLoopPlane
 from repro.serving.gateway import Gateway, QueuedRequest
 from repro.serving.kvcache import CacheLayout, PagedCacheLayout, PagePool
 from repro.serving.prefixcache import PrefixCachePlane
-from repro.serving.telemetry import EventBus, TelemetryPlane
+from repro.serving.telemetry import EventBus, TelemetryPlane, span
 from repro.serving.workers import (AttentionWorker, ClusterSlotView,
                                    ExpertWorker)
 
@@ -832,6 +832,7 @@ class InferenceEngine:
             seg_stack = [np.asarray(a)[t - base:t - base + count]
                          for a in self._extract_range(
                              self.cache, r.slot, base, count=shape)]
+            self.note_syncs()
             self._ck_range(ck, r.rid, t, seg_stack,
                            [self._ck_token_value(r, i)
                             for i in range(t, t + count)])
@@ -883,9 +884,11 @@ class InferenceEngine:
             for i, (r, start, _) in enumerate(ent):
                 slots[i] = r.slot
                 bases[i] = max(0, min(start, self.ecfg.max_seq - shape))
-            stacked = [np.asarray(a) for a in self._extract_multi(
-                self.cache, jnp.asarray(slots), jnp.asarray(bases),
-                count=shape)]
+            with span(self.telemetry, "engine", "checkpoint.device"):
+                stacked = [np.asarray(a) for a in self._extract_multi(
+                    self.cache, jnp.asarray(slots), jnp.asarray(bases),
+                    count=shape)]
+            self.note_syncs()
             for i, (r, start, cnt) in enumerate(ent):
                 off = start - bases[i]
                 seg_stack = [a[i][off:off + cnt] for a in stacked]
@@ -901,6 +904,7 @@ class InferenceEngine:
         segments stay token-granular and layout-independent either way —
         a paged AW's checkpoints restore onto a contiguous engine and
         vice versa."""
+        self.note_checkpoint(len(token_values), _seg_nbytes(seg_stack))
         if self.pages is not None:
             ck.checkpoint_blocks(rid, start, seg_stack, token_values,
                                  self.pages.page_tokens)
@@ -1291,7 +1295,21 @@ class InferenceEngine:
         """Drain a device-side per-slot dispatch counter into the placement
         manager's EMA (the telemetry behind load-aware decisions)."""
         if self.placement_mgr is not None:
+            if isinstance(slot_load, jax.Array):
+                self.note_syncs()
             self.placement_mgr.record_slot_load(np.asarray(slot_load))
+
+    def note_syncs(self, n: int = 1):
+        """Count ``n`` device->host drains (``step.device_syncs``)."""
+        if self.telemetry is not None:
+            self.telemetry.registry.inc("step.device_syncs", n)
+
+    def note_checkpoint(self, segments: int, nbytes: int):
+        """Count KV segments captured for checkpointing, and their bytes
+        (``checkpoint.segments``, ``checkpoint.bytes``)."""
+        if self.telemetry is not None:
+            self.telemetry.registry.inc("checkpoint.segments", segments)
+            self.telemetry.registry.inc("checkpoint.bytes", nbytes)
 
     def choose_protect_ew(self, exclude=()) -> Optional[int]:
         if self.placement_mgr is None:

@@ -6,7 +6,7 @@ failure-induced stalls of ~64 s collapsing to 0.3–0.4 s — yet until this
 plane the only way to audit them was to replay full per-request timestamp
 lists through ``np.percentile`` after the run, and failure causality lived
 in ad-hoc ``WorkerEvent`` drains only the orchestrator consumed. This
-module makes observation first-class, in four pieces:
+module makes observation first-class, in five pieces:
 
   * **StreamingHistogram / MetricsRegistry** — fixed log-bucket histograms
     (O(1) memory, mergeable) plus counters and gauges. p50/p95/p99 come
@@ -26,7 +26,12 @@ module makes observation first-class, in four pieces:
     done) with queued/prefill/decode phase sub-spans (each queued spell
     tagged with its cause: fresh, preempt, failover), restore/preempt/
     prefix-adopt/cancel instants, failure-detection spans on the worker
-    track, and per-step engine-track spans — all on the virtual clock.
+    track, and per-step engine-track spans.
+  * **Host spans** — ``TelemetryPlane.span`` brackets host work inside the
+    program (the engine step and its phases, checkpoint capture, failure
+    handling, each per-request restore) with a parent span id and a
+    ``jax.profiler.TraceAnnotation`` named ``tarragon.<name>``, so under a
+    profiler the span sits on the host plane beside the device ops.
   * **Stall attribution** — every TTFT/TBT gap above
     ``EngineConfig.stall_threshold`` is decomposed into
     {detection, restore, preemption, queue_wait, prefill, rebalance}
@@ -34,25 +39,41 @@ module makes observation first-class, in four pieces:
     intervals to the gap window in priority order; components always sum
     to the observed gap by construction.
 
+Two clocks, kept apart. Every span carries the caller's time ``t0``/``t1``
+(the virtual clock of ``run_serving``, or whatever ``now`` a wall-clock
+serving loop passes): lifecycle semantics and stall attribution use it
+alone. Spans opened and closed while the work runs also carry wall
+stamps ``w0``/``w1`` from ``time.perf_counter()``, the clock a profiler
+trace and a serving loop's own timers share. ``chrome_trace(clock=...)``
+exports one clock or the other, never both.
+
 Exporters: ``snapshot()`` (JSON, schema ``repro.telemetry.v1``),
 ``prometheus_text()`` (text exposition format), ``export_chrome()``
-(Perfetto/Chrome ``trace_event`` JSON).
+(Perfetto/Chrome ``trace_event`` JSON). ``live_planes()`` lists the planes
+of the engines alive in the process, for a reader outside the engine.
 
 Invariants: the plane is host-side bookkeeping only — it never touches
-device arrays and never calls into jax, so telemetry on/off is
+device arrays and never traces or syncs, so telemetry on/off is
 bit-identical and adds zero new jit traces (asserted in
-tests/test_telemetry.py, overhead measured in bench_steady_state).
+tests/test_telemetry.py). A host span costs two ``perf_counter`` reads
+and one profiler annotation, which records nothing unless a profiler is
+running.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
+import time
+import weakref
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import numpy as np
 
-from repro.core.orchestrator import WorkerEvent
+if TYPE_CHECKING:
+    from repro.core.orchestrator import WorkerEvent
 
 SCHEMA = "repro.telemetry.v1"
 
@@ -348,31 +369,51 @@ class EventBus:
 # ---------------------------------------------------------------------------
 
 
+#: category of the spans ``TelemetryPlane.span`` records: host work timed on
+#: the wall clock while it ran
+HOST = "host"
+
+
 @dataclass
 class Span:
-    track: str                 # "req:<rid>" | "engine" | "workers"
+    track: str                 # "req:<rid>" | "engine" | "workers" | ...
     name: str
-    t0: float
+    t0: float                  # the caller's clock
     t1: Optional[float] = None
     cat: str = "phase"
     args: Dict[str, object] = field(default_factory=dict)
+    w0: Optional[float] = None  # wall stamps (time.perf_counter), when the
+    w1: Optional[float] = None  # span was open while its work ran
+    sid: int = -1              # this span's id within its tracer
+    parent: int = -1           # id of the span that caused it (-1: none)
 
     @property
     def duration(self) -> float:
         return (self.t1 - self.t0) if self.t1 is not None else 0.0
 
+    @property
+    def wall(self) -> float:
+        """Wall-clock seconds the span was open (0 without wall stamps)."""
+        return (self.w1 - self.w0) \
+            if self.w0 is not None and self.w1 is not None else 0.0
+
 
 class SpanTracer:
-    """Virtual-clock span recorder with a Perfetto/Chrome ``trace_event``
-    exporter. Memory is bounded: past ``max_spans`` closed spans, new ones
-    are dropped and counted (``dropped``) rather than growing without
-    limit — a soak run keeps its histograms exact and its trace a prefix."""
+    """Span recorder with a Perfetto/Chrome ``trace_event`` exporter.
+    Memory is bounded: past ``max_spans`` closed spans, new ones are
+    dropped and counted (``dropped``) rather than growing without limit —
+    a soak run keeps its histograms exact and its trace a prefix.
+
+    ``begin``/``end`` and ``instant`` stamp the wall clock as they are
+    called; ``complete`` records a span after the fact on the caller's
+    clock alone."""
 
     def __init__(self, max_spans: int = 200_000):
         self.max_spans = max_spans
         self.spans: List[Span] = []
         self.instants: List[Span] = []
         self.dropped = 0
+        self._sid = 0
 
     def _room(self) -> bool:
         if len(self.spans) + len(self.instants) >= self.max_spans:
@@ -380,36 +421,66 @@ class SpanTracer:
             return False
         return True
 
+    def new(self, track: str, name: str, t: float, cat: str,
+            args: dict, parent: int = -1) -> Span:
+        """A span with the tracer's next id, not yet recorded."""
+        self._sid += 1
+        return Span(track, name, t, None, cat, args, sid=self._sid,
+                    parent=parent)
+
+    def add(self, span: Span) -> Span:
+        if self._room():
+            self.spans.append(span)
+        return span
+
     def begin(self, track: str, name: str, t: float, cat: str = "phase",
               **args) -> Span:
-        sp = Span(track, name, t, None, cat, dict(args))
-        if self._room():
-            self.spans.append(sp)
-        return sp
+        sp = self.new(track, name, t, cat, dict(args))
+        sp.w0 = time.perf_counter()
+        return self.add(sp)
 
     @staticmethod
     def end(span: Span, t: float, **args):
         span.t1 = t
+        span.w1 = time.perf_counter()
         span.args.update(args)
 
     def complete(self, track: str, name: str, t0: float, t1: float,
                  cat: str = "phase", **args) -> Span:
-        sp = Span(track, name, t0, t1, cat, dict(args))
-        if self._room():
-            self.spans.append(sp)
-        return sp
+        sp = self.new(track, name, t0, cat, dict(args))
+        sp.t1 = t1
+        return self.add(sp)
 
     def instant(self, track: str, name: str, t: float, **args) -> Span:
-        sp = Span(track, name, t, t, "instant", dict(args))
+        sp = self.new(track, name, t, "instant", dict(args))
+        sp.t1 = t
+        sp.w0 = sp.w1 = time.perf_counter()
         if self._room():
             self.instants.append(sp)
         return sp
 
     # -- Perfetto / Chrome trace_event JSON ---------------------------------
-    def chrome_trace(self, clock_end: Optional[float] = None) -> dict:
+    def chrome_trace(self, clock_end: Optional[float] = None,
+                     clock: str = "virtual") -> dict:
         """``{"traceEvents": [...]}``: one pid, one tid per track, complete
-        ("X") events for spans, instants ("i"), thread-name metadata. Times
-        are virtual seconds scaled to microseconds."""
+        ("X") events for spans, instants ("i"), thread-name metadata, in
+        microseconds of one clock. ``clock="virtual"``: the caller's time,
+        host spans left out (they take no time on that clock); an open
+        span ends at ``clock_end``. ``clock="wall"``: the wall stamps,
+        spans without them (recorded after the fact, or still open) left
+        out."""
+        if clock not in ("virtual", "wall"):
+            raise ValueError(f"clock must be 'virtual' or 'wall', not "
+                             f"{clock!r}")
+        if clock == "wall":
+            spans = [(sp, sp.w0, sp.w1) for sp in self.spans
+                     if sp.w0 is not None and sp.w1 is not None]
+            instants = [(sp, sp.w0) for sp in self.instants]
+        else:
+            spans = [(sp, sp.t0, sp.t1 if sp.t1 is not None else
+                      clock_end if clock_end is not None else sp.t0)
+                     for sp in self.spans if sp.cat != HOST]
+            instants = [(sp, sp.t0) for sp in self.instants]
         tids: Dict[str, int] = {}
 
         def tid_of(track: str) -> int:
@@ -418,11 +489,12 @@ class SpanTracer:
             return tids[track]
 
         # stable track order: engine/workers first, then request tracks
-        for sp in self.spans + self.instants:
-            if not sp.track.startswith("req:"):
-                tid_of(sp.track)
-        for sp in self.spans + self.instants:
-            tid_of(sp.track)
+        tracks = [sp.track for sp, *_ in spans + instants]
+        for track in tracks:
+            if not track.startswith("req:"):
+                tid_of(track)
+        for track in tracks:
+            tid_of(track)
 
         events: List[dict] = [
             {"ph": "M", "pid": 1, "name": "process_name",
@@ -430,18 +502,19 @@ class SpanTracer:
         for track, tid in tids.items():
             events.append({"ph": "M", "pid": 1, "tid": tid,
                            "name": "thread_name", "args": {"name": track}})
-        for sp in self.spans:
-            t1 = sp.t1 if sp.t1 is not None else \
-                (clock_end if clock_end is not None else sp.t0)
+        for sp, a, b in spans:
+            args = sp.args
+            if sp.cat == HOST:
+                args = dict(args, sid=sp.sid, parent=sp.parent)
             events.append({
                 "ph": "X", "pid": 1, "tid": tid_of(sp.track),
                 "name": sp.name, "cat": sp.cat,
-                "ts": sp.t0 * 1e6, "dur": max(t1 - sp.t0, 0.0) * 1e6,
-                "args": sp.args})
-        for sp in self.instants:
+                "ts": a * 1e6, "dur": max(b - a, 0.0) * 1e6,
+                "args": args})
+        for sp, a in instants:
             events.append({
                 "ph": "i", "pid": 1, "tid": tid_of(sp.track),
-                "name": sp.name, "cat": sp.cat, "ts": sp.t0 * 1e6,
+                "name": sp.name, "cat": sp.cat, "ts": a * 1e6,
                 "s": "t", "args": sp.args})
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -526,13 +599,30 @@ _PHASE_CAUSE = {("queued", "fresh"): "queue_wait",
                 ("prefill", None): "prefill"}
 
 
+_LIVE: "weakref.WeakSet[TelemetryPlane]" = weakref.WeakSet()
+
+
+def live_planes() -> List["TelemetryPlane"]:
+    """The planes of the engines alive in this process."""
+    return list(_LIVE)
+
+
+def span(plane: Optional["TelemetryPlane"], track: str, name: str, **args):
+    """``plane.span(track, name, **args)``, or a context that does nothing
+    where the plane is off (``plane`` None)."""
+    if plane is None:
+        return contextlib.nullcontext()
+    return plane.span(track, name, **args)
+
+
 class TelemetryPlane:
     """Per-engine observability plane: registry + tracer + stall
-    attribution, fed by host-side hooks at every lifecycle transition.
-    Created by the engine when ``EngineConfig.telemetry`` is True; every
-    hook site guards on ``engine.telemetry is not None``, and nothing here
-    ever touches device state — switching the plane off cannot change a
-    single token or mint a jit trace."""
+    attribution, fed by host-side hooks at every lifecycle transition and
+    by host spans around the engine's own work. Created by the engine when
+    ``EngineConfig.telemetry`` is True; every hook site guards on
+    ``engine.telemetry is not None``, and nothing here ever touches device
+    state — switching the plane off cannot change a single token or mint
+    a jit trace."""
 
     def __init__(self, engine):
         self.engine = engine
@@ -554,6 +644,38 @@ class TelemetryPlane:
         self._prefill_windows: List[Tuple[float, float]] = []
         self._stalls: List[StallRecord] = []
         self._attributed = False
+        # host spans open right now, innermost last
+        self._open: List[Span] = []
+        _LIVE.add(self)
+
+    # -- host spans ---------------------------------------------------------
+    @contextlib.contextmanager
+    def span(self, track: str, name: str, parent: Optional[Span] = None,
+             **args) -> Iterator[Span]:
+        """Time the host work inside the block as one closed span on
+        ``track``: wall stamps ``w0``/``w1`` around the block, the plane's
+        clock (``now``) as its caller time, and as ``parent`` the span
+        that caused it (default: the innermost host span open). Under a
+        profiler the block is a ``tarragon.<name>`` host event on the
+        device trace's clock. Adds no device sync and no jit trace: the
+        span only brackets code that already runs. The yielded span's
+        ``args`` may be filled in inside the block."""
+        if parent is None and self._open:
+            parent = self._open[-1]
+        sp = self.tracer.new(track, name, self.now, HOST, args,
+                             parent.sid if parent is not None else -1)
+        self._open.append(sp)
+        try:
+            with jax.profiler.TraceAnnotation(f"tarragon.{name}"):
+                sp.w0 = time.perf_counter()
+                try:
+                    yield sp
+                finally:
+                    sp.w1 = time.perf_counter()
+        finally:
+            sp.t1 = self.now
+            self._open.pop()
+            self.tracer.add(sp)
 
     # -- internals ----------------------------------------------------------
     def _touch(self, t: float) -> float:
@@ -930,9 +1052,10 @@ class TelemetryPlane:
         self.sync()
         return self.registry.prometheus_text()
 
-    def export_chrome(self, path: Optional[str] = None) -> dict:
+    def export_chrome(self, path: Optional[str] = None,
+                      clock: str = "virtual") -> dict:
         self.stall_report()
-        trace = self.tracer.chrome_trace(clock_end=self.now)
+        trace = self.tracer.chrome_trace(clock_end=self.now, clock=clock)
         if path:
             with open(path, "w") as f:
                 json.dump(trace, f)

@@ -1,9 +1,11 @@
 """Observability plane (serving/telemetry.py): streaming histograms vs
 exact percentiles, the multi-consumer event bus, per-request span
-completeness, stall attribution, exporter formats — and the two hard
-invariants across a serving run that spans an AW failure, preemptions, a
-queued cancel, and a prefix-warm chat turn: telemetry on/off is
-bit-identical, and the plane mints zero new jit traces."""
+completeness, stall attribution, exporter formats, the host spans and
+counters around the engine's own work — and the two hard invariants
+across a serving run that spans an AW failure, preemptions, a queued
+cancel, and a prefix-warm chat turn: telemetry on/off is bit-identical,
+and the plane mints zero new jit traces."""
+import gc
 import json
 import math
 
@@ -16,11 +18,13 @@ from repro.core.costmodel import TarragonProfile
 from repro.core.events import timeline_from_bus
 from repro.core.orchestrator import Orchestrator, WorkerEvent
 from repro.data.workloads import make_workload
+from repro.serving.api import RequestSpec
 from repro.serving.engine import EngineConfig, InferenceEngine
 from repro.serving.scheduler import FailurePlan, run_serving
-from repro.serving.telemetry import (SCHEMA, STALL_CAUSES, EventBus,
+from repro.serving.telemetry import (HOST, SCHEMA, STALL_CAUSES, EventBus,
                                      MetricsRegistry, StreamingHistogram,
-                                     attribute_gap, pct, summarize_latency)
+                                     attribute_gap, live_planes, pct,
+                                     summarize_latency)
 
 
 # --------------------------------------------------------------------------
@@ -217,6 +221,24 @@ def _workload():
     return sorted(slo + chat, key=lambda r: (r.arrival, r.request_id))
 
 
+def record_restores(eng):
+    """Keep, in ``eng.restored``, what ``CheckpointStore.restore_request``
+    returned to each per-request restore (the calls made inside a
+    ``restore.read`` span; the prefix plane's own restores are left
+    out)."""
+    eng.restored = []
+    read = eng.store.restore_request
+
+    def recording(rid):
+        out = read(rid)
+        tel = eng.telemetry
+        if tel is not None and tel._open and \
+                tel._open[-1].name == "restore.read":
+            eng.restored.append((rid, out))
+        return out
+    eng.store.restore_request = recording
+
+
 def scenario(telemetry: bool):
     """One serving run (cached per on/off) exercising every lifecycle
     path the plane traces: fresh admission, chunked prefill, preemption
@@ -230,6 +252,7 @@ def scenario(telemetry: bool):
                         preempt=True, placement="session_affinity",
                         telemetry=telemetry, stall_threshold=0.1)
     eng = InferenceEngine(cfg, ecfg, jax.random.PRNGKey(1))
+    record_restores(eng)
     orch = Orchestrator(eng, profile=TarragonProfile(detect=0.05,
                                                      detect_retries=2),
                         worker_init_time=0.5)
@@ -274,6 +297,15 @@ def test_telemetry_mints_zero_new_jit_traces():
         return eng._decode._cache_size() + eng.decode_plane.segment_traces()
 
     assert traces(eng_on) == traces(eng_off)
+    # the host spans bracket the chunk calls, the checkpoint gathers and
+    # the restores without tracing anything of their own
+    assert {sp.name for sp in host_spans(eng_on.telemetry)} >= {
+        "step", "chunk.device", "checkpoint.device", "recovery.restore"}
+    for fn in ("_prefill_chunk", "_extract"):
+        assert getattr(eng_on, fn)._cache_size() == \
+            getattr(eng_off, fn)._cache_size(), fn
+    assert eng_on.chunked._extract_range._cache_size() == \
+        eng_off.chunked._extract_range._cache_size()
     # and the snapshot's own gauge agrees (sync() reads, never compiles)
     snap = eng_on.telemetry.snapshot()
     assert snap["gauges"]["jit.decode_traces"] == traces(eng_on)
@@ -412,3 +444,178 @@ def test_telemetry_off_engine_has_no_plane():
     assert eng.gateway.telemetry is None
     # the bus still runs (it is the audit stream, not the telemetry plane)
     assert len(eng.bus.events) > 0
+
+
+# --------------------------------------------------------------------------
+# host spans and counters around the engine's own work
+# --------------------------------------------------------------------------
+
+def host_spans(tel, name=None):
+    return [sp for sp in tel.tracer.spans if sp.cat == HOST and
+            (name is None or sp.name == name)]
+
+
+#: the span each host span is opened inside (None: a root)
+PARENTS = {
+    "step": {None},
+    "step.hooks": {"step"}, "step.admit": {"step"}, "step.chunk": {"step"},
+    "step.decode": {"step"}, "step.checkpoint": {"step"},
+    "chunk.device": {"step.chunk"},
+    "decode.device": {"step.decode"},
+    "checkpoint.device": {"step.checkpoint"},
+    "recovery.detect": {None},
+    "recovery.ew_remap": {"recovery.detect"},
+    "recovery.aw_requeue": {"recovery.detect"},
+    # a restore runs where the request is admitted: in the detecting tick,
+    # in a later step's admission pass, or when a provisioned AW drains
+    # the queue
+    "recovery.restore": {"recovery.aw_requeue", "step.admit", None},
+    "restore.read": {"recovery.restore"},
+    "restore.write": {"recovery.restore"},
+}
+_SEGMENTED = {}
+
+
+def segmented_failover():
+    """Decode segments of 4 steps, an EW then an AW lost mid-decode."""
+    if not _SEGMENTED:
+        cfg = reduced("mixtral_8x7b", cap_factor=4.0)
+        ecfg = EngineConfig(max_batch=8, max_seq=96, num_aw=2, num_ew=2,
+                            chunk_token_budget=16, decode_segment_len=4)
+        eng = InferenceEngine(cfg, ecfg, jax.random.PRNGKey(2))
+        record_restores(eng)
+        orch = Orchestrator(eng, profile=TarragonProfile(
+            detect=0.05, detect_retries=2), worker_init_time=0.5)
+        wl = make_workload("random", rate_rps=8.0, duration=1.0, seed=5,
+                           max_prompt=40, max_new=24)
+        run_serving(eng, wl, duration=60.0, orchestrator=orch,
+                    failures=[FailurePlan(0.3, "ew", 0),
+                              FailurePlan(0.5, "aw", 0)],
+                    step_time=STEP, prefill_token_time=PF_TOK)
+        _SEGMENTED["run"] = eng
+    return _SEGMENTED["run"]
+
+
+def host_run(which: str):
+    return scenario(True)[0] if which == "per_step" else \
+        segmented_failover()
+
+
+@pytest.mark.parametrize("which", ["per_step", "segmented"])
+def test_host_spans_nest_under_their_parents(which):
+    eng = host_run(which)
+    spans = host_spans(eng.telemetry)
+    by_id = {sp.sid: sp for sp in spans}
+    seen = set()
+    for sp in spans:
+        parent = by_id.get(sp.parent)
+        assert (parent.name if parent else None) in PARENTS[sp.name], \
+            (sp.name, parent)
+        assert sp.w0 <= sp.w1
+        if parent is not None:
+            assert parent.w0 <= sp.w0 and sp.w1 <= parent.w1, sp.name
+        seen.add(sp.name)
+    # the per-step scenario loses no EW
+    assert set(PARENTS) - {"recovery.ew_remap"} <= seen
+    assert which == "per_step" or "recovery.ew_remap" in seen
+    # steps follow one another on the wall clock
+    steps = [sp for sp in spans if sp.name == "step"]
+    assert all(a.w1 <= b.w0 for a, b in zip(steps, steps[1:]))
+    # the detect spans carry what failed
+    assert {(sp.args["kind"], sp.args["worker"]) for sp in spans
+            if sp.name == "recovery.detect"} >= {("aw", 0)}
+
+
+@pytest.mark.parametrize("which", ["per_step", "segmented"])
+def test_restore_spans_and_counters_match_the_store(which):
+    """One recovery.restore per restored request, and its segments and
+    bytes are what the store's restore_request handed back."""
+    from repro.core.checkpoint import _seg_nbytes
+    eng = host_run(which)
+    tel = eng.telemetry
+    spans = host_spans(tel, "recovery.restore")
+    assert spans and len(spans) == len(eng.restored)
+    want = [(rid, len(segs), sum(_seg_nbytes(x) for x in segs.values()))
+            for rid, (_, _, segs) in eng.restored]
+    assert [(sp.args["rid"], sp.args["segments"], sp.args["bytes"])
+            for sp in spans] == want
+    c = tel.registry.counters
+    assert c["restore.segments"] == sum(n for _, n, _ in want) > 0
+    assert c["restore.bytes"] == sum(b for _, _, b in want)
+    leaves = len(eng.layout.token_segment(eng.cache, 0, 0))
+    assert c["restore.cache_writes"] == leaves * c["restore.segments"]
+    # the store wrote what the engine captured
+    assert c["checkpoint.bytes"] == eng.store.stats.bytes_written
+    assert c["checkpoint.segments"] == eng.store.stats.updates
+
+
+def decoding_engine(seg_len: int, checkpoint: bool):
+    cfg = reduced("mixtral_8x7b", cap_factor=4.0)
+    ecfg = EngineConfig(max_batch=4, max_seq=48, num_aw=2, num_ew=2,
+                        decode_segment_len=seg_len, checkpoint=checkpoint)
+    eng = InferenceEngine(cfg, ecfg, jax.random.PRNGKey(4))
+    for i in range(3):
+        eng.client.submit(RequestSpec(
+            rid=f"r{i}", prompt=np.arange(1, 9, dtype=np.int32) + i,
+            max_new=40))
+    eng.step()            # admission and the whole-prompt prefill
+    eng.step()
+    assert len(eng.active_requests()) == 3
+    return eng
+
+
+@pytest.mark.parametrize("seg_len,checkpoint,drains", [
+    # per-step: the dispatch-load counter, the token vector, the batched
+    # checkpoint gather
+    (1, True, 3),
+    (1, False, 2),
+    # a segment: the token ring (with its load counters), one multi-slot
+    # checkpoint gather for the segment's KV
+    (4, True, 2),
+    (4, False, 1)])
+def test_device_syncs_count_the_drains_of_a_step(seg_len, checkpoint,
+                                                 drains):
+    eng = decoding_engine(seg_len, checkpoint)
+    assert eng.collect_load
+    c = eng.telemetry.registry.counters
+    for _ in range(3):
+        before = c["step.device_syncs"]
+        eng.step()
+        assert c["step.device_syncs"] - before == drains
+    # one checkpoint.device span per gather, inside its step.checkpoint
+    names = [sp.name for sp in host_spans(eng.telemetry)]
+    assert names.count("checkpoint.device") == \
+        (names.count("step.checkpoint") if checkpoint else 0)
+
+
+def test_chrome_trace_keeps_the_two_clocks_apart():
+    eng, _, m = scenario(True)
+    tr = m.telemetry.tracer
+    virtual = tr.chrome_trace(clock_end=m.telemetry.now)
+    wall = tr.chrome_trace(clock="wall")
+    v_names = {e["name"] for e in virtual["traceEvents"] if e["ph"] == "X"}
+    w_events = [e for e in wall["traceEvents"] if e["ph"] == "X"]
+    w_names = {e["name"] for e in w_events}
+    # host spans take no time on the caller's clock; spans recorded after
+    # the fact (stall, detection window) have no wall stamps
+    assert "step.decode" in w_names and "step.decode" not in v_names
+    assert "detect_aw0" in v_names and "detect_aw0" not in w_names
+    # wall microseconds are perf_counter's; every host event names its
+    # parent
+    host = [e for e in w_events if e["cat"] == HOST]
+    assert host and all({"sid", "parent"} <= set(e["args"]) for e in host)
+    lo = min(sp.w0 for sp in tr.spans if sp.w0 is not None)
+    assert min(e["ts"] for e in w_events) == pytest.approx(lo * 1e6)
+    with pytest.raises(ValueError):
+        tr.chrome_trace(clock="both")
+
+
+def test_live_planes_lists_the_planes_of_live_engines():
+    eng = decoding_engine(1, True)
+    plane = eng.telemetry
+    assert any(p is plane for p in live_planes())
+    del eng, plane
+    gc.collect()
+    n = len(live_planes())
+    eng = decoding_engine(1, True)
+    assert len(live_planes()) == n + 1
