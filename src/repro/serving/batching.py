@@ -292,18 +292,15 @@ class ContinuousBatchScheduler:
                     # — the committed segments then scatter through the
                     # block table
                     eng._kv_ensure(slot, max(segs) + 1)
-                cache = eng.cache
-                for t, seg in segs.items():
-                    cache = eng.layout.write_token_segment(cache, slot, t,
-                                                           seg)
-                eng.cache = cache
+                eng.cache = eng.layout.write_token_segments(
+                    eng.cache, slot, list(segs), list(segs.values()))
             if tel is not None:
                 nbytes = sum(_seg_nbytes(seg) for seg in segs.values())
                 sp.args.update(segments=len(segs), bytes=nbytes)
                 tel.registry.inc("restore.segments", len(segs))
                 tel.registry.inc("restore.bytes", nbytes)
                 tel.registry.inc("restore.cache_writes",
-                                 sum(len(seg) for seg in segs.values()))
+                                 -(-len(segs) // eng.layout.block_tokens))
 
         r.slot = slot
         r._aw = aw

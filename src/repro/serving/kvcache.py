@@ -10,8 +10,9 @@ per-request operations:
       attention leaves -> the single KV column the decode step just wrote
       (size C = 2*Hkv*head_dim, App. C); state leaves (SSM/xLSTM/cross-KV)
       -> the current constant-size snapshot.
-  * ``write_token_segment`` — per-request restoration (§6.2): inject a
-      committed segment into any healthy AW's cache slot.
+  * ``write_token_segments`` — per-request restoration (§6.2): inject a
+      request's committed segments into any healthy AW's cache slot, a
+      block of rows per compiled scatter.
   * ``request_state`` / ``write_request_state`` — whole-slot copy (used for
       request migration and the pause-checkpoint-resume baseline).
 """
@@ -27,6 +28,29 @@ import numpy as np
 def _path_str(path) -> str:
     return "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                     for p in path)
+
+
+# rows a contiguous layout's restore scatter takes per call (a paged
+# layout takes one page's worth)
+RESTORE_BLOCK = 128
+
+
+def _stack_rows(segs, i: int, dtype, rows: int) -> np.ndarray:
+    """Leaf ``i`` of every segment stacked on the host into one array of
+    ``rows`` rows (zero padded) in the cache leaf's dtype."""
+    out = np.zeros((rows,) + np.shape(segs[0][i]), dtype)
+    out[:len(segs)] = np.stack([s[i] for s in segs])
+    return out
+
+
+def _last_of(keys: np.ndarray) -> np.ndarray:
+    """Mask of the rows whose key recurs at no later row: the writes a
+    token-by-token loop would leave standing. XLA leaves the order of
+    duplicate scatter indices undefined, so the others never reach it."""
+    _, first = np.unique(keys[::-1], return_index=True)
+    keep = np.zeros(len(keys), bool)
+    keep[len(keys) - 1 - first] = True
+    return keep
 
 
 class CacheLayout:
@@ -58,6 +82,8 @@ class CacheLayout:
                 self.leaf_kind.append("attn_" + leaf)
             else:
                 self.leaf_kind.append("state")
+        self.block_tokens = RESTORE_BLOCK
+        self._write_rows_fn = jax.jit(self._write_rows_impl)
 
     # ------------------------------------------------------------------
     def _leaves(self, cache):
@@ -87,19 +113,55 @@ class CacheLayout:
             seg.append(np.asarray(per_req))
         return seg
 
-    def write_token_segment(self, cache, slot: int, token: int,
-                            seg: List[Any]):
-        leaves, treedef = self._leaves(cache)
-        out = []
-        for leaf, ax, kind, s in zip(leaves, self.batch_axis,
-                                     self.leaf_kind, seg):
+    def write_token_segments(self, cache, slot: int, tokens, segs):
+        """Per-request restoration (§6.2): write the committed segments
+        ``segs`` of ``tokens`` (ascending token indices, gaps allowed) into
+        ``slot``. An attention leaf takes each token's column at ``token %
+        Sc``, where on a ring the latest token of a position wins; a state
+        leaf takes the last token's snapshot. The rows are stacked on the
+        host and go to the device ``block_tokens`` at a time through one
+        compiled scatter whose shapes never change."""
+        if not len(tokens):
+            return cache
+        leaves, _ = self._leaves(cache)
+        toks = np.asarray(tokens, np.int64)
+        k = self.block_tokens
+        rows = -(-len(toks) // k) * k
+        idx, vals = [], []
+        for i, (leaf, ax, kind) in enumerate(zip(leaves, self.batch_axis,
+                                                 self.leaf_kind)):
             if kind.startswith("attn_"):
                 sc = leaf.shape[ax + 1]
-                idx = (slice(None),) * ax + (slot, token % sc)
+                pos = toks % sc
+                ix = np.full(rows, sc, np.int32)     # sc: dropped
+                ix[:len(toks)] = np.where(_last_of(pos), pos, sc)
+                idx.append(ix)
+                vals.append(_stack_rows(segs, i, leaf.dtype, rows))
             else:
-                idx = (slice(None),) * ax + (slot,)
-            out.append(jnp.asarray(leaf).at[idx].set(
-                jnp.asarray(s, leaf.dtype)))
+                idx.append(None)
+                vals.append(np.asarray(segs[-1][i], leaf.dtype))
+        slot = np.int32(slot)
+        for b in range(0, rows, k):
+            cache = self._write_rows_fn(
+                cache, slot, [None if ix is None else ix[b:b + k]
+                              for ix in idx],
+                [v if ix is None else v[b:b + k]
+                 for ix, v in zip(idx, vals)])
+        return cache
+
+    def _write_rows_impl(self, cache, slot, idx, vals):
+        """One block of a restore: attention leaf ``i`` takes its rows
+        ``vals[i]`` at ring positions ``idx[i]`` of ``slot`` (out of range:
+        dropped); a state leaf takes its snapshot again, which leaves the
+        same result however many blocks there are."""
+        leaves, treedef = self._leaves(cache)
+        out = []
+        for leaf, ax, ix, v in zip(leaves, self.batch_axis, idx, vals):
+            if ix is None:
+                out.append(leaf.at[(slice(None),) * ax + (slot,)].set(v))
+            else:
+                out.append(leaf.at[(slice(None),) * ax + (slot, ix)].set(
+                    jnp.moveaxis(v, 0, ax), mode="drop"))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     # ------------------------------------------------------------------
@@ -309,8 +371,10 @@ class PagedCacheLayout:
         self.batch_axis = self.inner.batch_axis
         self.leaf_kind = self.inner.leaf_kind
         self.attn_parents = self.inner.attn_parents
+        self.block_tokens = page_tokens
         self._copy_page_fn = jax.jit(self._copy_page_impl)
         self._scrub_pages_fn = jax.jit(self._scrub_pages_impl)
+        self._write_rows_fn = jax.jit(self._write_rows_impl)
 
     # ------------------------------------------------------------------
     def make_cache(self, init_cache_fn, batch: int, num_pages: int):
@@ -364,20 +428,40 @@ class PagedCacheLayout:
             seg.append(np.asarray(per))
         return seg
 
-    def write_token_segment(self, cache, slot: int, token: int,
-                            seg: List[Any]):
+    def write_token_segments(self, cache, slot: int, tokens, segs):
+        """Per-request restoration into the slot's mapped pages, a page's
+        worth of rows per call of one compiled scatter that reads the
+        block-table row on the device. A token in an unmapped block (the
+        null page: the host failed to pre-allocate) drops its write
+        instead of corrupting the shared null page; of tokens that share
+        a position modulo ``max_seq`` the latest wins."""
+        if not len(tokens):
+            return cache
+        _, leaves, _ = self._rest(cache)
+        toks = np.asarray(tokens, np.int64) % self.max_seq
+        k = self.block_tokens
+        rows = -(-len(toks) // k) * k
+        tk = np.full(rows, -1, np.int32)             # -1: dropped
+        tk[:len(toks)] = np.where(_last_of(toks), toks, -1)
+        vals = [_stack_rows(segs, i, leaf.dtype, rows)
+                for i, leaf in enumerate(leaves)]
+        slot = np.int32(slot)
+        for b in range(0, rows, k):
+            cache = self._write_rows_fn(cache, slot, tk[b:b + k],
+                                        [v[b:b + k] for v in vals])
+        return cache
+
+    def _write_rows_impl(self, cache, slot, tk, vals):
         bt, leaves, treedef = self._rest(cache)
         pt = self.page_tokens
-        page = bt[slot, (token % self.max_seq) // pt]
-        off = token % pt
+        row = jax.lax.dynamic_index_in_dim(bt, slot, 0, keepdims=False)
+        page = jnp.take(row, tk // pt, mode="clip")
+        off = tk % pt
         out = []
-        for leaf, ax, s in zip(leaves, self.inner.batch_axis, seg):
-            # an unmapped block (page 0 — the host failed to pre-allocate)
-            # drops the write instead of corrupting the shared null page
-            safe = jnp.where(page > 0, page, leaf.shape[ax])
-            idx = (slice(None),) * ax + (safe, off)
-            out.append(jnp.asarray(leaf).at[idx].set(
-                jnp.asarray(s, leaf.dtype), mode="drop"))
+        for leaf, ax, v in zip(leaves, self.inner.batch_axis, vals):
+            dest = jnp.where((tk >= 0) & (page > 0), page, leaf.shape[ax])
+            out.append(leaf.at[(slice(None),) * ax + (dest, off)].set(
+                jnp.moveaxis(v, 0, ax), mode="drop"))
         return self._rebuild(bt, out, treedef)
 
     # ------------------------------------------------------------------

@@ -798,11 +798,8 @@ class PrefixCachePlane:
                 eng._kv_ensure(slot, n)
             except RuntimeError:
                 return False      # page pool exhausted on target
-            cache = eng.cache
-            for t in range(n):
-                cache = eng.layout.write_token_segment(cache, slot, t,
-                                                       segs[t])
-            eng.cache = cache
+            eng.cache = eng.layout.write_token_segments(
+                eng.cache, slot, range(n), [segs[t] for t in range(n)])
             ok = bool(target.prefix_cache.offer(
                 slot, np.asarray(tokens[:n], np.int32), rid, session, now))
             if ok:
@@ -909,11 +906,9 @@ class PrefixCachePlane:
                 eng.store.release(e.rid)
                 continue
             slot = target.slots.alloc()
-            cache = eng.layout.clear_slot(eng.cache, slot)
-            for t in range(n):
-                cache = eng.layout.write_token_segment(cache, slot, t,
-                                                       segs[t])
-            eng.cache = cache
+            eng.cache = eng.layout.write_token_segments(
+                eng.layout.clear_slot(eng.cache, slot), slot, range(n),
+                [segs[t] for t in range(n)])
             eng.store.reassign(e.rid, target.aw_id)
             if target.prefix_cache.insert_restored(
                     slot, e.tokens[:n], e.rid, e.session, now):

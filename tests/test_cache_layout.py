@@ -7,7 +7,7 @@ import pytest
 
 from conftest import make_batch, reduced
 from repro.models import get_model
-from repro.serving.kvcache import CacheLayout
+from repro.serving.kvcache import CacheLayout, PagedCacheLayout
 from repro.serving.workers import AttentionWorker, ClusterSlotView
 
 
@@ -44,14 +44,153 @@ def test_token_segment_roundtrip_attention(arch, key):
     _, cache = api.prefill(params, batch, rs, max_seq=16)
     # segment-by-segment copy of slot 0 into a fresh cache slot 1
     fresh = api.init_cache(2, 16)
-    for t in range(8):
-        seg = layout.token_segment(cache, 0, t)
-        fresh = layout.write_token_segment(fresh, 1, t, seg)
+    segs = [layout.token_segment(cache, 0, t) for t in range(8)]
+    fresh = layout.write_token_segments(fresh, 1, range(8), segs)
     want = layout.request_state(cache, 0)
     got = layout.request_state(fresh, 1)
     for a, b, kind in zip(want, got, layout.leaf_kind):
         if kind.startswith("attn_"):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def token_loop(layout, cache, slot, tokens, segs):
+    """The restore write ``write_token_segments`` replaced, kept as its
+    reference: one eager scatter per token and cache leaf."""
+    paged = isinstance(layout, PagedCacheLayout)
+    if paged:
+        bt, leaves, treedef = layout._rest(cache)
+    else:
+        leaves, treedef = layout._leaves(cache)
+    for t, seg in zip(tokens, segs):
+        out = []
+        for leaf, ax, kind, s in zip(leaves, layout.batch_axis,
+                                     layout.leaf_kind, seg):
+            if paged:
+                page = bt[slot, (t % layout.max_seq) // layout.page_tokens]
+                safe = jnp.where(page > 0, page, leaf.shape[ax])
+                idx = (safe, t % layout.page_tokens)
+            elif kind.startswith("attn_"):
+                idx = (slot, t % leaf.shape[ax + 1])
+            else:
+                idx = (slot,)
+            out.append(leaf.at[(slice(None),) * ax + idx].set(
+                jnp.asarray(s, leaf.dtype), mode="drop"))
+        leaves = out
+    if paged:
+        return layout._rebuild(bt, leaves, treedef)
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def random_array(rng, shape, dtype) -> np.ndarray:
+    if jnp.issubdtype(dtype, jnp.integer):
+        return rng.integers(-1, 1000, shape).astype(dtype)
+    return rng.normal(size=shape).astype(dtype)
+
+
+def random_like(rng, a):
+    return jnp.asarray(random_array(rng, a.shape, a.dtype))
+
+
+def seg_shape(leaf, ax: int, kind: str):
+    """A token segment's shape: the leaf without its slot (or page) axis
+    and, for attention leaves, its position axis."""
+    drop = (ax, ax + 1) if kind.startswith("attn_") else (ax,)
+    return tuple(d for i, d in enumerate(leaf.shape) if i not in drop)
+
+
+GAP = list(range(100)) + list(range(140, 200))
+
+
+def case(arch, paged, restores, unmapped=None, name=""):
+    lens = "+".join(str(len(t)) for _, t in restores)
+    return pytest.param(arch, paged, restores, unmapped, id=f"{arch}-"
+                        f"{'paged' if paged else 'contiguous'}-{name or lens}")
+
+
+@pytest.mark.parametrize("arch,paged,restores,unmapped", [
+    *[case("mixtral_8x7b", paged, [(1, range(n))])
+      for paged in (True, False) for n in (1, 127, 128, 129, 300)],
+    case("mixtral_8x7b", True, [(1, GAP)], name="gap"),
+    case("mixtral_8x7b", False, [(1, GAP)], name="gap"),
+    case("mixtral_8x7b", True, [(1, range(300))], unmapped=1,
+         name="unmapped"),
+    case("mixtral_8x7b", True, [(1, range(450))], name="wrap"),
+    case("gemma2_2b", False, [(1, range(300))], name="ring"),
+    case("zamba2_7b", False, [(1, range(129))], name="state"),
+    case("mixtral_8x7b", True, [(0, range(1)), (1, range(129)),
+                                (2, range(300))]),
+    case("mixtral_8x7b", False, [(0, range(1)), (1, range(129)),
+                                 (2, range(300))]),
+])
+def test_write_token_segments_match_the_token_loop(arch, paged, restores,
+                                                   unmapped):
+    """The batched restore write equals the token-by-token loop bit for
+    bit: block edges, gaps, an unmapped block (dropped, the null page
+    untouched), a wrapping ring (the latest token wins), state leaves (the
+    last snapshot wins); and its shapes never change, so restores of any
+    length share one compiled program."""
+    cfg = reduced(arch)
+    api = get_model(cfg, num_aw=1, num_ew=2)
+    rng = np.random.default_rng(0)
+    max_seq = 384
+    if paged:
+        layout = PagedCacheLayout(api.init_cache, 128, max_seq)
+        cache = layout.make_cache(api.init_cache, 3, num_pages=10)
+        bt = np.arange(1, 10, dtype=np.int32).reshape(3, 3)
+        if unmapped is not None:
+            bt[1, unmapped] = 0
+        cache = {k: v if k == "bt" else jax.tree_util.tree_map(
+                     lambda a: random_like(rng, a), v)
+                 for k, v in cache.items()}
+        cache = layout.set_block_table(cache, bt)
+        leaves = layout._rest(cache)[1]
+    else:
+        layout = CacheLayout(api.init_cache)
+        cache = jax.tree_util.tree_map(lambda a: random_like(rng, a),
+                                       api.init_cache(3, max_seq))
+        leaves = layout._leaves(cache)[0]
+    # XLA leaves the order of duplicate scatter indices undefined (the
+    # CPU happens to apply them in order): no call may name a position
+    # twice, nor a position another call of the same restore names
+    calls = []
+    write = layout._write_rows_fn
+
+    def recorded(cache, slot, idx, vals):
+        calls.append(idx)
+        return write(cache, slot, idx, vals)
+
+    layout._write_rows_fn = recorded
+    start = cache
+    want = got = cache
+    for slot, tokens in restores:
+        segs = [[random_array(rng, seg_shape(leaf, ax, kind), leaf.dtype)
+                 for leaf, ax, kind in zip(leaves, layout.batch_axis,
+                                           layout.leaf_kind)]
+                for _ in tokens]
+        want = token_loop(layout, want, slot, list(tokens), segs)
+        calls.clear()
+        got = layout.write_token_segments(got, slot, list(tokens), segs)
+        assert len(calls) == -(-len(tokens) // layout.block_tokens)
+        if paged:
+            named = [(np.concatenate(calls), max_seq)]
+        else:
+            named = [(np.concatenate([c[i] for c in calls]),
+                      leaf.shape[ax + 1])
+                     for i, (leaf, ax, kind) in enumerate(zip(
+                         leaves, layout.batch_axis, layout.leaf_kind))
+                     if kind.startswith("attn_")]
+        for ix, bound in named:
+            ix = ix[(ix >= 0) & (ix < bound)]
+            assert len(np.unique(ix)) == len(ix)
+    for a, b in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if paged:
+        for a, b, ax in zip(layout._rest(start)[1], layout._rest(got)[1],
+                            layout.batch_axis):
+            np.testing.assert_array_equal(np.take(np.asarray(a), 0, ax),
+                                          np.take(np.asarray(b), 0, ax))
+    assert write._cache_size() == 1
 
 
 def test_attention_nodes_detected():
