@@ -3,10 +3,11 @@
 Once the window has closed, a sample of the requests it served -- drawn
 from the seed, always with the longest one, with the requests a failure
 struck, and grown to some hundreds of served tokens -- is run through the
-float32 reference (``bench/reference/model.py``) over prompt + served
-tokens. At each position where a token was served, the gap is how far the
-served token's reference logit lies below the reference's best logit (0
-where they agree). The number compared is the clipped mean gap: the mean
+float32 reference of the configuration's family (``logits`` of
+``bench/reference/<family>.py``) over prompt + served tokens. At each
+position where a token was served, the gap is how far the served token's
+reference logit lies below the reference's best logit (0 where they
+agree). The number compared is the clipped mean gap: the mean
 over the sample's served positions of the gap, each cut off at ``CLIP``
 logits. A router near-tie now and then sends a sound bfloat16 token to
 another expert than float32 does, and such a position reads a gap of up
@@ -26,6 +27,7 @@ from typing import Dict, Iterable, List
 import numpy as np
 
 from harness.loop import Served
+from harness.spec import family
 
 MIN_TOKENS = 300     # served tokens the sample grows to
 MAX_STRUCK = 6       # struck requests kept in the sample
@@ -61,7 +63,7 @@ def position_gaps(w: dict, c: dict, served: Served, rids: List[str],
     served token (with ``control``, the float8 reference's first choice)
     lies below the float32 reference's best logit, and ``miss``, whether it
     is not that best."""
-    from reference.model import logits
+    logits = family(c).logits
     served_tokens = served.tokens()
     out = {}
     for rid in rids:

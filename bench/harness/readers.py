@@ -12,6 +12,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from harness import flops, trace
+from harness.weights import dims
 
 
 @dataclass
@@ -87,7 +88,7 @@ def moe_gemm_roofline(run: Run):
     """Kernels: least time of the expert FFN's routed work (weights of the
     experts reached, routed rows) over moe_gemm's device time (%)."""
     c, pk = run.config, run.peaks
-    layers = c["num_hidden_layers"]
+    layers = dims(c)["L"]
     tokens = [len(i["ctx"]) for _, _, i in _calls(run, "decode", True)]
     tokens += [sum(n for _, n in i["rows"]) for _, _, i in
                _calls(run, "chunk", True)]
@@ -100,7 +101,7 @@ def decode_attention_roofline(run: Run):
     """Kernels: least time of decode attention over the real context
     lengths over decode_attention_paged's device time (%)."""
     c, pk = run.config, run.peaks
-    least = sum(c["num_hidden_layers"] * flops.least_time(
+    least = sum(dims(c)["L"] * flops.least_time(
         *flops.decode_attention_cost(c, i["ctx"]), pk)
         for _, _, i in _calls(run, "decode", True) if len(i["ctx"]))
     return _roofline(run, "decode_attention_paged", least)
@@ -110,7 +111,7 @@ def flash_attention_roofline(run: Run):
     """Kernels: least time of causal chunk attention over the real tokens
     over flash_attention's device time (%)."""
     c, pk = run.config, run.peaks
-    least = sum(c["num_hidden_layers"] * flops.least_time(
+    least = sum(dims(c)["L"] * flops.least_time(
         *flops.flash_attention_cost(c, i["rows"]), pk)
         for _, _, i in _calls(run, "chunk", True) if i["rows"])
     return _roofline(run, "flash_attention", least)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from harness import check, loop, model, readers, spec, trace, traffic
-from harness.weights import make_weights
+from harness.weights import dims, make_weights
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # a trace run profiles TRACE_SECONDS from TRACE_FROM of the way into the
@@ -201,7 +201,7 @@ def setup(cell: spec.Cell, seed: int, t_process: float, counter):
     log(f"[setup] weights and engine: {time.monotonic() - t_process:.3f} s "
         f"since process start, {counter.count} programs")
     warm_programs(eng)
-    warm_serving(eng, orch, cell, c["vocab_size"])
+    warm_serving(eng, orch, cell, dims(c)["V"])
     eng.gateway.stats.queue_delay.clear()
     return eng, orch, w
 
@@ -218,9 +218,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     runs."""
     counter = CompileCounter()
     c = cell.config
-    vocab = c["vocab_size"]
+    d = dims(c)
     eng, orch, w = setup(cell, seed, t_process, counter)
-    reqs = traffic.generate(cell.traffic, seed, seconds, vocab)
+    reqs = traffic.generate(cell.traffic, seed, seconds, d["V"])
     fails = traffic.failures(cell.traffic, seconds)
     if fault is not None:
         fault(eng)
@@ -309,8 +309,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         f" widest gap {got['max_gap']}, mean gap {got['mean_gap']}, "
         f"mismatch share {got['mismatch_share']} (not compared)")
     # every token of the window routed to top-k experts in every layer
-    routed = c["num_experts_per_tok"] * c["num_hidden_layers"] * \
-        (served.prefill_tokens + n_tokens)
+    routed = d["K"] * d["L"] * (served.prefill_tokens + n_tokens)
     dropped = int(round(routed - dispatched.total))
     correct = got["tokens"] > 0 and got["clipped_mean_gap"] <= limit and \
         dropped == 0

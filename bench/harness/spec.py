@@ -3,19 +3,24 @@
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``. Its configuration
 is ``bench/configs/<config>.json`` (the file named in ``configs``), its
 traffic mix ``bench/traffic/<traffic>.json``, and its per-layer metrics are
-``bench/metrics/<metric>.py``. Nothing here names a cell: a later cell, mix
-or metric is a new file and a new entry.
+``bench/metrics/<metric>.py``. The configuration's ``"family"`` names its
+architecture's module, ``bench/reference/<family>.py``: its widths, weight
+layout, program tree and reference. Nothing here names a cell or a family:
+a later cell, mix, metric or architecture is a new file and a new entry.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parent
+REFERENCE_DIR = BENCH_DIR / "reference"
 
 
 @dataclass
@@ -83,6 +88,30 @@ def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def family(c: dict) -> ModuleType:
+    """The module of configuration ``c``'s architecture family,
+    ``bench/reference/<family>.py``. A configuration that names no family
+    is refused: there is no default."""
+    if "family" not in c:
+        raise ValueError("the configuration names no 'family', the module "
+                         f"of its architecture in {REFERENCE_DIR}")
+    return load_family(c["family"], REFERENCE_DIR)
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(name: str, directory: Path) -> ModuleType:
+    """``<directory>/<name>.py``, loaded once per process, so that its
+    jitted functions keep their compiled programs."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"no family {name!r}: {path} is not there")
+    spec = importlib.util.spec_from_file_location(
+        "bench_family_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_peaks(kind: str, bench_dir: Path = BENCH_DIR) -> Dict[str, float]:

@@ -1,15 +1,10 @@
 """Weights of a configuration, drawn on the device from the seed.
 
-One jitted call draws every leaf straight into the served dtype. The layout
-is the reference's (``bench/reference/model.py``), stacked over layers:
-
-    embed [V, D]   unembed [V, D]   final_norm [D]
-    layers: ln1, ln2 [L, D]; wq [L, D, H*Dh]; wk, wv [L, D, Hkv*Dh];
-            wo [L, H*Dh, D];
-            router [L, D, E]; w_gate, w_up [L, E, D, F]; w_down [L, E, F, D]
-
-The program is handed the same arrays in its own tree (``harness.model``);
-the reference reads them here. Neither makes weights of its own for the run.
+One jitted call draws every leaf straight into the served dtype. The tree
+and its shapes are the configuration's family's (``weight_shapes`` of
+``bench/reference/<family>.py``); the family's ``program_params`` hands the
+program the same arrays in its own tree (``harness.model``), and its
+reference reads them here. Neither makes weights of its own for the run.
 """
 from __future__ import annotations
 
@@ -17,42 +12,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness.spec import family
+
 
 def dims(c: dict) -> dict:
-    """The widths of configuration ``c`` under short names."""
-    return {
-        "L": c["num_hidden_layers"], "D": c["hidden_size"],
-        "H": c["num_attention_heads"], "Hkv": c["num_key_value_heads"],
-        "Dh": c["head_dim"], "V": c["vocab_size"],
-        "E": c["num_local_experts"], "K": c["num_experts_per_tok"],
-        "F": c["intermediate_size"],
-    }
-
-
-def weight_shapes(c: dict) -> dict:
-    d = dims(c)
-    L, D, H, Hkv, Dh, V, E, F = (d[k] for k in
-                                 ("L", "D", "H", "Hkv", "Dh", "V", "E", "F"))
-    layers = {
-        "ln1": (L, D), "ln2": (L, D),
-        "wq": (L, D, H * Dh), "wk": (L, D, Hkv * Dh), "wv": (L, D, Hkv * Dh),
-        "wo": (L, H * Dh, D),
-        "router": (L, D, E),
-        "w_gate": (L, E, D, F), "w_up": (L, E, D, F), "w_down": (L, E, F, D),
-    }
-    out = {"embed": (V, D), "final_norm": (D,), "layers": layers}
-    if not c["tie_word_embeddings"]:
-        out["unembed"] = (V, D)
-    return out
+    """The widths of configuration ``c`` under short names (``L D H Hkv Dh
+    V E K F``), read through its family's ``KEYS``."""
+    return {k: c[key] for k, key in family(c).KEYS.items()}
 
 
 def make_weights(c: dict, seed: int):
     """Every weight of configuration ``c`` from ``seed``, on the default
     device, in the dtype it is served in, in one jitted call. Matrices are
-    normal with std 1/sqrt(fan-in) (the embedding std 1); norm scales are
-    1 + N(0, 0.1), so that a path that skipped a norm's scale would show."""
+    normal with std 1/sqrt(fan-in) (the embedding std 1); norm scales,
+    every leaf whose path names a norm (``norm``, ``ln``), are 1 + N(0,
+    0.1), so that a path that skipped a norm's scale would show."""
     dtype = jnp.dtype(c["torch_dtype"])
-    shapes = weight_shapes(c)
+    shapes = family(c).weight_shapes(c)
     flat, tree = jax.tree_util.tree_flatten(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
     paths = [jax.tree_util.keystr(p) for p, _ in

@@ -1,5 +1,12 @@
-"""Plain forward pass of a sparse mixture-of-experts decoder, from the
-published equations, in float32.
+"""The Mixtral family: its config keys, its weight layout, the program's tree
+for those weights, and a plain forward pass from the published equations,
+in float32.
+
+The harness finds this module by a configuration file's ``"family":
+"mixtral"`` (``harness.spec.family``) and reads everything particular to
+the architecture from it: ``KEYS`` (the short width names mapped to this
+family's config keys), ``weight_shapes``, ``model_config``,
+``program_params``, ``arch`` and ``logits``.
 
 Mixtral-8x7B (arXiv:2401.04088, section 2), with x the residual stream of
 one sequence:
@@ -36,6 +43,80 @@ import numpy as np
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
+
+# short width names -> this family's config keys (``harness.weights.dims``)
+KEYS = {"L": "num_hidden_layers", "D": "hidden_size",
+        "H": "num_attention_heads", "Hkv": "num_key_value_heads",
+        "Dh": "head_dim", "V": "vocab_size", "E": "num_local_experts",
+        "K": "num_experts_per_tok", "F": "intermediate_size"}
+
+
+def weight_shapes(c: dict) -> dict:
+    """Every weight of configuration ``c``, stacked over layers:
+
+        embed [V, D]   unembed [V, D] (untied only)   final_norm [D]
+        layers: ln1, ln2 [L, D]; wq [L, D, H*Dh]; wk, wv [L, D, Hkv*Dh];
+                wo [L, H*Dh, D];
+                router [L, D, E]; w_gate, w_up [L, E, D, F];
+                w_down [L, E, F, D]
+    """
+    L, D, H, Hkv, Dh, V, E, F = (c[KEYS[k]] for k in
+                                 ("L", "D", "H", "Hkv", "Dh", "V", "E", "F"))
+    layers = {
+        "ln1": (L, D), "ln2": (L, D),
+        "wq": (L, D, H * Dh), "wk": (L, D, Hkv * Dh), "wv": (L, D, Hkv * Dh),
+        "wo": (L, H * Dh, D),
+        "router": (L, D, E),
+        "w_gate": (L, E, D, F), "w_up": (L, E, D, F), "w_down": (L, E, F, D),
+    }
+    out = {"embed": (V, D), "final_norm": (D,), "layers": layers}
+    if not c["tie_word_embeddings"]:
+        out["unembed"] = (V, D)
+    return out
+
+
+def model_config(c: dict, name: str):
+    """The program's ``ModelConfig`` of configuration file ``c``. Refuses
+    what the program cannot compute as published."""
+    from repro.configs.base import ModelConfig, MoEConfig
+    if not c.get("norm_topk_prob", True):
+        raise ValueError(f"{name}: the program always renormalises the "
+                         "top-k gate weights; norm_topk_prob=false is not "
+                         "served")
+    if c.get("sliding_window") or c.get("attention_bias"):
+        raise ValueError(f"{name}: sliding windows and attention biases "
+                         "are not configured here")
+    return ModelConfig(
+        name=name, arch_type="moe", source=c["source"],
+        num_layers=c[KEYS["L"]], d_model=c[KEYS["D"]],
+        num_heads=c[KEYS["H"]], num_kv_heads=c[KEYS["Hkv"]],
+        head_dim=c[KEYS["Dh"]], d_ff=0, vocab_size=c[KEYS["V"]],
+        rope_theta=float(c["rope_theta"]),
+        qk_norm=bool(c["qk_norm"]), act=c["hidden_act"],
+        tie_embeddings=bool(c["tie_word_embeddings"]),
+        norm_eps=float(c["rms_norm_eps"]), dtype=c["torch_dtype"],
+        moe=MoEConfig(num_experts=c[KEYS["E"]], top_k=c[KEYS["K"]],
+                      d_ff=c[KEYS["F"]]))
+
+
+def program_params(w: dict, c: dict):
+    """The weights ``w`` in the transformer stack's tree: one scanned unit
+    per layer. The arrays are shared, not copied."""
+    layers = w["layers"]
+    attn = {k: layers[k] for k in ("wq", "wk", "wv", "wo")}
+    block = {
+        "ln1": {"scale": layers["ln1"]},
+        "attn": attn,
+        "ln2": {"scale": layers["ln2"]},
+        "moe": {"router": layers["router"],
+                "experts": {"wg": layers["w_gate"], "wu": layers["w_up"],
+                            "wd": layers["w_down"]}},
+    }
+    out = {"embed": w["embed"], "final_norm": {"scale": w["final_norm"]},
+           "blocks": (block,)}
+    if "unembed" in w:
+        out["unembed"] = w["unembed"]
+    return out
 
 
 class Arch(NamedTuple):

@@ -11,7 +11,8 @@ window serves the cell's traffic on the wall clock for ``--seconds``. With
 ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
 window and the harness's own spans. Either way, what the window served is
-checked against the float32 reference (``bench/reference``).
+checked against the float32 reference of the configuration's family
+(``bench/reference/<family>.py``, named by the configuration file).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` with

@@ -66,6 +66,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=1)
     args = ap.parse_args()
     from harness import loop, session, spec, traffic
+    from harness.weights import dims
     cell = spec.load_cell(args.workload)
     import jax
     bench_run.use_checkout_cache(jax)
@@ -79,7 +80,7 @@ def main() -> int:
     eng, orch, _ = session.setup(cell, args.seed, t_start, counter)
     print(json.dumps({"engine": cell.config["engine"],
                       "setup_s": time.monotonic() - t_start}), flush=True)
-    vocab = cell.config["vocab_size"]
+    vocab = dims(cell.config)["V"]
     missed = 0
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         mix = copy.deepcopy(cell.traffic)

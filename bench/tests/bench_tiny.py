@@ -1,8 +1,11 @@
 """Small copies of the benchmark's cells for the CPU tests.
 
-Each keeps its configuration's structure (expert count, top-k, untied head, norm eps, RoPE theta, engine layout) and shrinks only the
-widths, depth of the cache and the traffic, so that a whole run fits in
-seconds on the CPU. The chip runs use the files as they are.
+Each keeps its configuration's structure (expert count, top-k, untied head,
+norm eps, RoPE theta, engine layout) and shrinks only the widths, depth of
+the cache and the traffic, so that a whole run fits in seconds on the CPU.
+Widths are set by their short names through the family's ``KEYS``, so a
+cell of any family shrinks alike. The chip runs use the files as they
+are.
 """
 from __future__ import annotations
 
@@ -20,9 +23,7 @@ for p in (str(ROOT / "src"), str(BENCH)):
 
 from harness import spec  # noqa: E402
 
-SMALL_WIDTHS = {"hidden_size": 64, "num_attention_heads": 4,
-                "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
-                "intermediate_size": 32}
+SMALL_WIDTHS = {"D": 64, "H": 4, "Hkv": 2, "Dh": 16, "V": 256, "F": 32}
 SMALL_ENGINE = {"max_batch": 4, "max_seq": 256, "kv_page_tokens": 32,
                 "chunk_token_budget": 32, "chunk_min": 32}
 SMALL_LENGTHS = {"prompt_tokens": {"median": 24, "sigma": 0.6, "min": 8,
@@ -45,10 +46,16 @@ def small_cell(name: str, experts: int = None) -> spec.Cell:
         cell = spec.Cell(name, 1, config, json.loads(
             (BENCH / "configs" / f"{config}.json").read_text()), traffic,
             json.loads((BENCH / "traffic" / f"{traffic}.json").read_text()))
+    return shrink(cell, experts)
+
+
+def shrink(cell: spec.Cell, experts: int = None) -> spec.Cell:
+    """``cell`` at small widths, engine and traffic, in place."""
     c = cell.config
-    c.update(SMALL_WIDTHS)
+    keys = spec.family(c).KEYS
+    c.update({keys[k]: v for k, v in SMALL_WIDTHS.items()})
     if experts is not None:
-        c["num_local_experts"] = experts
+        c[keys["E"]] = experts
     c["engine"].update(SMALL_ENGINE)
     c["check"] = {"clipped_mean_gap": SMALL_LIMIT}
     cell.traffic.update(copy.deepcopy(SMALL_LENGTHS))
@@ -58,6 +65,19 @@ def small_cell(name: str, experts: int = None) -> spec.Cell:
     else:
         a["requests"] = 6
     return cell
+
+
+def paced(eng, seconds: float = 0.01):
+    """Make each engine step last ``seconds`` more. A small cell's step
+    takes about a millisecond on a fast CPU, so its short requests can all
+    have finished before a failure strikes; paced like this (a chip's step
+    takes ~130 ms) some are decoding when one does, on any host."""
+    step = eng.step
+
+    def slow(*args, **kwargs):
+        time.sleep(seconds)
+        return step(*args, **kwargs)
+    eng.step = slow
 
 
 def cpu_device() -> dict:

@@ -7,7 +7,7 @@ import math
 import types
 
 import pytest
-from bench_tiny import run_small, small_cell
+from bench_tiny import paced, run_small, small_cell
 
 from harness import program_spans, readers
 from harness.loop import Served
@@ -22,12 +22,15 @@ OWN = {"mixtral-8x7b-2l.chat": ("step_host_ms.chat", "checkpoint_ms.chat"),
 def traced(request):
     cell = copy.deepcopy(small_cell(request.param))
     cell.config["torch_dtype"] = "float32"
+    fault = None
     if cell.traffic["failures"]:
         # answers long enough that both AWs hold decoding requests when
         # the last failure strikes, inside the traced part of the window
         cell.traffic["output_tokens"] = {"median": 40, "sigma": 0.2,
                                          "min": 30, "max": 60}
-    return request.param, run_small(cell, 6, seconds=3.0, traced=True)
+        fault = paced
+    return request.param, run_small(cell, 6, seconds=3.0, traced=True,
+                                    fault=fault)
 
 
 def test_each_cell_reads_its_own_span_metrics(traced):

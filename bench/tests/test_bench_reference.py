@@ -12,10 +12,10 @@ import time
 
 import jax.numpy as jnp
 import pytest
-from bench_tiny import SMALL_LIMIT, run_small, small_cell
+from bench_tiny import SMALL_LIMIT, paced, run_small, small_cell
 
 from harness import check, loop, model, session, traffic
-from harness.weights import make_weights
+from harness.weights import dims, make_weights
 
 CHAT, FAILOVER = "mixtral-8x7b-2l.chat", "mixtral-8x7b-2l.failover"
 
@@ -35,16 +35,16 @@ def f32(cell):
 def drain(cell, seed=3, fault=None):
     """Serve a backlog of the cell's mix to the end; returns (weights,
     served, routes the program dropped)."""
-    c = cell.config
+    c, d = cell.config, dims(cell.config)
     eng, orch, w = model.build(c, cell.config_name, seed,
                                lambda: make_weights(c, seed))
     if fault is not None:
         fault(eng)
     count = session.DispatchCount(eng)
-    reqs = traffic.generate(backlog(cell), seed, 1.0, c["vocab_size"])
+    reqs = traffic.generate(backlog(cell), seed, 1.0, d["V"])
     s = loop.serve(eng, orch, reqs, [], float("inf"),
                      stop=loop.drained(eng, orch))
-    routed = c["num_experts_per_tok"] * c["num_hidden_layers"] * (
+    routed = d["K"] * d["L"] * (
         s.prefill_tokens + sum(len(v) for v in s.stamps.values()))
     return w, s, reqs, routed - count.total
 
@@ -135,7 +135,7 @@ def test_a_failover_run_is_correct():
     """The failover mix at small widths: its failures strike inside the
     window, the struck requests are in the sample, and what the restored
     requests and the shadow experts served agrees with the reference."""
-    out = run_small(f32(small_cell(FAILOVER)), 6, seconds=3.0)
+    out = run_small(f32(small_cell(FAILOVER)), 6, seconds=3.0, fault=paced)
     assert out["correct"] is True
     assert out["compared"]["clipped_mean_gap"]["value"] <= SMALL_LIMIT
     assert set(out["metrics"]) == {"failure_stall_s", "setup_s"}
@@ -149,7 +149,7 @@ def test_requests_due_while_the_loop_is_held_are_attempted():
     c = cell.config
     eng, orch, _ = model.build(c, cell.config_name, 3,
                                lambda: make_weights(c, 3))
-    reqs = traffic.generate(cell.traffic, 3, 2.0, c["vocab_size"])
+    reqs = traffic.generate(cell.traffic, 3, 2.0, dims(c)["V"])
     tick = orch.tick
 
     def held(now):
