@@ -110,6 +110,25 @@ def intra_slot_positions(slot_idx, valid, num_slots: int):
     return pos.reshape(t, k)
 
 
+def dispatch_geometry(t: int, num_experts: int, top_k: int,
+                      capacity_factor: float,
+                      capacity: Optional[int] = None):
+    """(groups, group size, capacity per slot and group) of routing ``t``
+    tokens: one group up to ONEHOT_MAX_TOKENS, else groups of at most
+    GROUP_SIZE; ``capacity`` None is derived from the factor."""
+    if t > ONEHOT_MAX_TOKENS:
+        s_g = GROUP_SIZE
+        while t % s_g:
+            s_g //= 2
+        g = t // s_g
+    else:
+        g, s_g = 1, t
+    if capacity is None:
+        capacity = int(max(1, round(capacity_factor * top_k * s_g /
+                                    num_experts)))
+    return g, s_g, capacity
+
+
 def route(x, router_logits, route_state: RouteState,
           placement: ert_lib.ExpertPlacement, *, top_k: int,
           capacity_factor: float, capacity: Optional[int] = None,
@@ -163,16 +182,9 @@ def route(x, router_logits, route_state: RouteState,
     if token_mask is not None:
         token_valid = token_valid & token_mask
 
+    g, s_g, capacity = dispatch_geometry(t, e, top_k, capacity_factor,
+                                         capacity)
     grouped = t > ONEHOT_MAX_TOKENS
-    if grouped:
-        s_g = GROUP_SIZE
-        while t % s_g:
-            s_g //= 2
-        g = t // s_g
-    else:
-        g, s_g = 1, t
-    if capacity is None:
-        capacity = int(max(1, round(capacity_factor * top_k * s_g / e)))
 
     valid = token_valid[:, None] & (gate_w > 0)
     # intra-slot rank per GROUP (per-group capacity)

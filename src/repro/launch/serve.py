@@ -46,18 +46,26 @@ def parse_scale(s: str) -> ScalePlan:
     return ScalePlan(float(t), kind, int(wid) if wid else -1)
 
 
+def dropless_capacity_factor(moe) -> float:
+    """experts / top-k: the factor at which a slot's capacity
+    (factor x top-k x tokens / experts) equals the tokens of the call.
+    Mixtral's 8 / 2 is 4.0; Qwen3-30B-A3B's 128 / 8 is 16.0."""
+    return moe.num_experts / moe.top_k
+
+
 def build_server(cfg, ecfg: EngineConfig, *, seed: int = 0,
                  ew_policy: str = "revive", rebalance: bool = False):
     """The serving stack: one InferenceEngine (Gateway, AWs and EWs, the
     ERT/shadow-slot datapath, the checkpoint store) under an Orchestrator.
 
-    MoE layers serve at capacity factor 4.0: no expert slot overflows, so
-    the tokens a failure masks cannot change how the surviving requests'
-    tokens are routed, and token streams stay identical across failures.
-    Returns (engine, orchestrator)."""
+    MoE layers serve dropless, at ``dropless_capacity_factor``: each slot's
+    capacity is the call's token count, and a token picks an expert once,
+    so no expert slot overflows. The tokens a failure masks then cannot
+    change how the surviving requests' tokens are routed, and token streams
+    stay identical across failures. Returns (engine, orchestrator)."""
     if cfg.moe.enabled:
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=4.0))
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, capacity_factor=dropless_capacity_factor(cfg.moe)))
     eng = InferenceEngine(cfg, ecfg, jax.random.PRNGKey(seed))
     orch = Orchestrator(eng, worker_init_time=1.0, weight_push_time=0.25,
                         ew_policy=ew_policy, auto_rebalance=rebalance)

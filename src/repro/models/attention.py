@@ -59,7 +59,8 @@ def _project_q(cfg, params, x):
         q = q + params["bq"].astype(q.dtype)
     q = q.reshape(b, s, cfg.num_heads, cfg.head_dim_)
     if "q_norm" in params:
-        q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
+        with jax.named_scope("qk_norm"):
+            q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
     return q
 
 
@@ -73,7 +74,8 @@ def _project_kv(cfg, params, x):
     k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim_)
     v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim_)
     if "k_norm" in params:
-        k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
+        with jax.named_scope("qk_norm"):
+            k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
     return k, v
 
 

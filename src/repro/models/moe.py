@@ -87,11 +87,16 @@ def moe_apply(cfg: ModelConfig, params, x, route_state: refe.RouteState,
         bank = shadow_lib.resident_slot_bank(params["experts"],
                                              route_state.slot_expert)
 
+    # the kernel skips the slots no token reached (idle shadows, cold
+    # experts): their rows of expert_in are zero, and so is their output
+    counts = routing["slot_load"].astype(jnp.int32)
+
     def expert_fn(expert_in):
         with jax.named_scope("expert_ffn"):
             return kops.expert_ffn(expert_in, bank["wg"].astype(x.dtype),
                                    bank["wu"].astype(x.dtype),
-                                   bank["wd"].astype(x.dtype), act=cfg.act)
+                                   bank["wd"].astype(x.dtype), act=cfg.act,
+                                   counts=counts)
 
     y = refe.expert_io(xt, routing, expert_fn)
 

@@ -39,8 +39,8 @@ class ModelApi(NamedTuple):
     # (recurrent state / ring buffers / enc-dec).
     prefill_chunk: Optional[Callable[..., Any]] = None
     # True when prefill/decode/prefill_chunk accept a static ``with_load``
-    # flag appending the accumulated per-slot dispatch-load counter [P] to
-    # their returns (the placement manager's telemetry).
+    # flag appending the per-layer, per-slot dispatch-load counter [L, P]
+    # to their returns (the placement manager's telemetry).
     reports_load: bool = False
     # True when ``decode`` may be scanned into multi-token device segments
     # (serving/decode_loop.py): requires a pure positional cache (pos -1
@@ -195,8 +195,10 @@ def build_decoder(cfg: ModelConfig, *, num_aw: int = 1, num_ew: int = 1,
 
     def _run_stack(params, x, mode, positions=None, pos=None, caches=None,
                    route_state=None, capacity=None, token_mask=None):
+        """-> (x, caches, aux, load): ``load`` [num_layers, P] is each
+        layer's per-slot dispatch count."""
         aux_total = jnp.zeros((), jnp.float32)
-        load_total = jnp.zeros((n_slots,), jnp.float32)
+        loads = []
         new_caches = {} if caches is not None else None
         # paged engines carry one block table at the top of the cache dict;
         # it is threaded to every attention layer and returned unchanged.
@@ -211,14 +213,14 @@ def build_decoder(cfg: ModelConfig, *, num_aw: int = 1, num_ew: int = 1,
                 route_state=route_state, placement=placement,
                 capacity=capacity, token_mask=token_mask, bt=bt)
             aux_total += aux
-            load_total += load
+            loads.append(load[None])
             if caches is not None:
                 new_caches[f"dense{i}"] = nc
 
         def unit_body(carry, xs):
-            h, auxc, loadc = carry
+            h, auxc = carry
             unit_params, unit_caches = xs
-            ncs = []
+            ncs, unit_loads = [], []
             for i in range(u):
                 c = unit_caches[i] if unit_caches is not None else None
                 h, nc, aux, load = _layer_apply(
@@ -227,25 +229,26 @@ def build_decoder(cfg: ModelConfig, *, num_aw: int = 1, num_ew: int = 1,
                     route_state=route_state, placement=placement,
                     capacity=capacity, token_mask=token_mask, bt=bt)
                 auxc += aux
-                loadc += load
                 ncs.append(nc)
+                unit_loads.append(load)
             ncs = tuple(ncs) if caches is not None else None
-            return (h, auxc, loadc), ncs
+            return (h, auxc), (ncs, jnp.stack(unit_loads))
 
         body = jax.checkpoint(unit_body) if cfg.remat else unit_body
         if caches is None:
-            (x, aux_total, load_total), _ = jax.lax.scan(
-                lambda c, p: body(c, (p, None)), (x, aux_total, load_total),
+            (x, aux_total), (_, unit_loads) = jax.lax.scan(
+                lambda c, p: body(c, (p, None)), (x, aux_total),
                 params["blocks"])
         else:
-            (x, aux_total, load_total), nb = jax.lax.scan(
-                unit_body, (x, aux_total, load_total),
+            (x, aux_total), (nb, unit_loads) = jax.lax.scan(
+                unit_body, (x, aux_total),
                 (params["blocks"], caches["blocks"]))
             new_caches["blocks"] = nb
             if bt is not None:
                 new_caches["bt"] = bt
+        loads.append(unit_loads.reshape(r * u, n_slots))
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return x, new_caches, aux_total, load_total
+        return x, new_caches, aux_total, jnp.concatenate(loads)
 
     def forward_train(params, batch, route_state):
         tokens = batch["tokens"]
@@ -260,8 +263,9 @@ def build_decoder(cfg: ModelConfig, *, num_aw: int = 1, num_ew: int = 1,
                 with_load: bool = False):
         """batch may carry a ``mask`` ([B, S] bool) flagging real tokens;
         pads then never compete for expert capacity (pad-free dispatch).
-        ``with_load`` (static) appends the summed per-slot dispatch-load
-        counter to the returns (placement-manager telemetry)."""
+        ``with_load`` (static) appends the per-layer, per-slot
+        dispatch-load counter [L, P] to the returns (placement-manager
+        telemetry)."""
         tokens = batch["tokens"]
         b, s = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))

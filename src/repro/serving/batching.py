@@ -187,7 +187,7 @@ class ContinuousBatchScheduler:
             last_logits, req_cache, load = eng._prefill(
                 eng.params, batch, rs_pre, max_seq=eng.ecfg.max_seq,
                 with_load=True, **kw)
-            eng.note_dispatch_load(load)
+            eng.drain_dispatch_load(load, toks.size, capacity)
         else:
             last_logits, req_cache = eng._prefill(
                 eng.params, batch, rs_pre, max_seq=eng.ecfg.max_seq, **kw)
@@ -401,13 +401,14 @@ class ContinuousBatchScheduler:
                 # mapped
                 eng._kv_ensure(r.slot, r.pos + 1)
             pos_dev = jnp.asarray(pos)
-            with span(tel, "engine", "decode.device"):
+            with span(tel, "engine", "decode.device") as sp:
                 if eng.collect_load:
                     logits, eng.cache, load = eng._decode(
                         eng.params, jnp.asarray(tokens), pos_dev, eng.cache,
                         eng.route_state, capacity=eng.decode_capacity,
                         with_load=True)
-                    eng.note_dispatch_load(load)
+                    eng.drain_dispatch_load(load, tokens.size,
+                                            eng.decode_capacity, sp)
                 else:
                     logits, eng.cache = eng._decode(
                         eng.params, jnp.asarray(tokens), pos_dev, eng.cache,
@@ -467,13 +468,15 @@ class ContinuousBatchScheduler:
         tel = eng.telemetry
         seg_len = eng.decode_plane.seg_len
         with span(tel, "engine", "step.decode"):
-            with span(tel, "engine", "decode.device"):
+            with span(tel, "engine", "decode.device") as sp:
                 ring, loads = eng.decode_plane.run_segment(act, seg_len)
+                if eng.collect_load:
+                    for i in range(seg_len):
+                        eng.drain_dispatch_load(
+                            loads[i], eng.ecfg.max_batch,
+                            eng.decode_capacity, sp)
             eng.note_syncs()
             self.gateway.stats.host_syncs += 1     # the per-segment drain
-            if eng.collect_load:
-                for i in range(seg_len):
-                    eng.note_dispatch_load(loads[i])
 
             out: Dict[str, List[int]] = {}
             max_seq = eng.ecfg.max_seq

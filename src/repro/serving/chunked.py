@@ -272,13 +272,14 @@ class ChunkedPrefillPlane:
         # health must not mask its tokens; EW health still applies
         rs_pre = eng.route_state._replace(
             aw_health=jnp.ones_like(eng.route_state.aw_health))
-        with span(eng.telemetry, "engine", "chunk.device"):
+        with span(eng.telemetry, "engine", "chunk.device") as sp:
             if eng.collect_load:
                 eng.cache, load = eng._prefill_chunk(
                     eng.params, jnp.asarray(toks), jnp.asarray(pos),
                     eng.cache, rs_pre, capacity=eng.prefill_capacity(real),
                     with_load=True)
-                eng.note_dispatch_load(load)
+                eng.drain_dispatch_load(load, toks.size,
+                                        eng.prefill_capacity(real), sp)
             else:
                 eng.cache = eng._prefill_chunk(
                     eng.params, jnp.asarray(toks), jnp.asarray(pos),

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core import selfheal
+from repro.core import refe, selfheal
 from repro.core.checkpoint import CheckpointStore, _seg_nbytes
 from repro.core.orchestrator import WorkerEvent
 from repro.core.placement import ExpertPlacementManager, PlacementPlan
@@ -1292,12 +1292,40 @@ class InferenceEngine:
             if self.placement_mgr is not None else 0
 
     def note_dispatch_load(self, slot_load):
-        """Drain a device-side per-slot dispatch counter into the placement
-        manager's EMA (the telemetry behind load-aware decisions)."""
+        """Drain a device-side dispatch counter ([L, P] per layer and slot)
+        into the placement manager's EMA (the telemetry behind load-aware
+        decisions), summed over layers."""
         if self.placement_mgr is not None:
             if isinstance(slot_load, jax.Array):
                 self.note_syncs()
-            self.placement_mgr.record_slot_load(np.asarray(slot_load))
+            load = np.asarray(slot_load)
+            self.placement_mgr.record_slot_load(
+                load.reshape(-1, load.shape[-1]).sum(0))
+
+    def drain_dispatch_load(self, slot_load, tokens: int, capacity, sp=None):
+        """One jitted call's per-layer load ``slot_load`` [L, P]: into the
+        placement manager (``note_dispatch_load``), and, with telemetry on,
+        into the counters ``moe.routed_rows`` (the (token, expert) pairs
+        dispatched) and ``moe.computed_rows`` (the rows the expert kernel
+        computes: groups x capacity for each slot of a layer that got a
+        token), also added to the args of ``sp``, the call's device span.
+        ``tokens`` and ``capacity`` are the call's routed token count and
+        its capacity argument. Reads the host copy the drain already made:
+        no further device sync."""
+        self.note_dispatch_load(slot_load)
+        if self.telemetry is None:
+            return
+        m = self.cfg.moe
+        groups, _, cap = refe.dispatch_geometry(
+            tokens, m.num_experts, m.top_k, m.capacity_factor, capacity)
+        load = np.asarray(slot_load)
+        routed = int(load.sum())
+        computed = groups * cap * int((load > 0).sum())
+        for name, n in (("routed_rows", routed),
+                        ("computed_rows", computed)):
+            self.telemetry.registry.inc(f"moe.{name}", n)
+            if sp is not None:
+                sp.args[name] = sp.args.get(name, 0) + n
 
     def note_syncs(self, n: int = 1):
         """Count ``n`` device->host drains (``step.device_syncs``)."""

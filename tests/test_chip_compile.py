@@ -84,3 +84,15 @@ def test_flash_attention_compiles(one_chip):
              ((B, PROMPT, H, DH), bf), ((B, PROMPT, HKV, DH), bf),
              ((B, PROMPT, HKV, DH), bf), ((B, PROMPT), i32),
              ((B, PROMPT), i32))
+
+
+@pytest.mark.parametrize("capacity", [16, 128])   # decode step / a chunk
+def test_moe_gemm_compiles_at_qwen3_widths(one_chip, capacity):
+    """Qwen3-30B-A3B's bank: D 2048, expert FF 768, 128 primary + 128
+    shadow slots, with the per-slot token counts the served path passes
+    (empty slots skip their MXU work)."""
+    bf, i32 = jnp.bfloat16, jnp.int32
+    d, f, slots = 2048, 768, 256
+    _compile(lambda x, wg, wu, wd, n: moe_gemm(x, wg, wu, wd, counts=n),
+             one_chip, ((slots, capacity, d), bf), ((slots, d, f), bf),
+             ((slots, d, f), bf), ((slots, f, d), bf), ((slots,), i32))

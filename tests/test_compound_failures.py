@@ -125,6 +125,41 @@ def test_aw_and_ew_die_in_same_detection_window():
     assert orch.outstanding == 0
 
 
+def test_aw_and_ew_lost_mid_decode_dropless_at_e_over_k_16():
+    """Qwen3-30B-A3B's ratio of experts to choices (E/k = 16; here 32
+    experts, top-2) through ``build_server``, which serves it dropless:
+    AW0 and EW0 lost mid-decode, with both AWs decoding, leave every
+    request's tokens as the failure-free run's."""
+    from repro.launch.serve import build_server
+    cfg = reduced("mixtral_8x7b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, num_experts=32, top_k=2))
+    prompts = {f"r{i}": np.arange(1 + i, 9 + 2 * i, dtype=np.int32)
+               for i in range(4)}
+
+    def served(fail: bool):
+        eng, orch = build_server(cfg, EngineConfig(
+            max_batch=8, max_seq=48, num_aw=2, num_ew=2,
+            chunk_token_budget=16))
+        assert eng.cfg.moe.capacity_factor == 16.0
+        for rid, p in prompts.items():
+            eng.client.submit(RequestSpec(rid=rid, prompt=p, max_new=14))
+        for _ in range(6):
+            eng.step()
+        if fail:
+            assert {eng.requests[r].aw for r in prompts} == {0, 1}
+            assert all(eng.requests[r].tokens for r in prompts)
+            orch.inject_failure("aw", 0, now=5.0)
+            orch.inject_failure("ew", 0, now=5.0)
+            orch.tick(5.0 + orch.detection_latency() + 1e-6)
+            assert eng.failed_aws == {0} and eng.failed_ews == {0}
+        while eng.active_requests():
+            eng.step()
+        return {r: eng.requests[r].tokens for r in prompts}
+
+    assert served(True) == served(False)
+
+
 def test_compound_failure_during_chunked_prefill():
     """AW dies mid-chunked-prefill AND an EW dies in the same window: the
     prefill stream resumes from its committed cursor on the healthy AW

@@ -4,6 +4,7 @@ profiler trace can be put down to the router, the slot-bank gather,
 dispatch, the expert FFN, combine, attention and the KV write. The
 scopes are metadata only: they change no logit."""
 import contextlib
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -48,3 +49,18 @@ def test_scopes_change_no_logit(decode_step, monkeypatch):
     hlo, plain = compiled_decode(api, args)
     assert not any(f"/{scope}/" in hlo for scope in SCOPES)
     np.testing.assert_array_equal(plain, logits)
+
+
+def test_the_qk_norm_has_its_scope():
+    """A model with a per-head q/k norm (Qwen3's) names it ``qk_norm``;
+    one without has no such op."""
+    for qk_norm in (True, False):
+        cfg = dataclasses.replace(reduced("mixtral_8x7b", cap_factor=4.0),
+                                  qk_norm=qk_norm)
+        api = get_model(cfg, num_aw=2, num_ew=2, tarragon=True)
+        args = (api.init_params(jax.random.PRNGKey(0)),
+                jnp.array([3, 5, 7, 9], jnp.int32),
+                jnp.array([4, 0, 9, -1], jnp.int32),
+                api.init_cache(4, 32), api.init_route_state())
+        hlo, _ = compiled_decode(api, args)
+        assert ("/qk_norm/" in hlo) == qk_norm
